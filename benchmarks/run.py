@@ -17,14 +17,18 @@ def main() -> None:
                    bench_maintenance, bench_read_path, bench_shard_scale,
                    bench_sparse_formats, roofline)
     print("name,us_per_call,derived")
+    failed = []
     for mod in (bench_dense_ftsf, bench_sparse_formats, bench_kernels,
                 bench_grad_compress, roofline, bench_read_path,
                 bench_shard_scale, bench_maintenance):
         try:
             for line in mod.run():
                 print(line)
-        except Exception as e:  # keep the harness running end to end
+        except Exception as e:  # run every module, then fail the harness
             print(f"{mod.__name__}_ERROR,0.0,{type(e).__name__}: {e}")
+            failed.append(mod.__name__)
+    if failed:
+        raise SystemExit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

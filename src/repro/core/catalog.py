@@ -590,8 +590,8 @@ class TensorRef:
                     use_pallas: Optional[bool] = None):
         """Read straight into a jax device buffer (numpy when jax can't).
 
-        FTSF reads stage chunk payloads once and reorder on the device via
-        ``block_gather``; COO reads scatter sparse pairs on the device via
+        FTSF reads stage chunk payloads into output order and transfer
+        them once; COO reads scatter sparse pairs on the device via
         ``coo_scatter`` — neither materializes an ordered full tensor on
         the host. Other layouts (and dtypes jax cannot hold bit-exactly,
         e.g. float64 without ``jax_enable_x64``) take the documented

@@ -131,13 +131,12 @@ class FTSFCodec(Codec):
 
     def decode_device(self, groups: List[Dict[str, Any]],
                       spec: SliceSpec = None, *, use_pallas=None):
-        """Chunk rows -> device tensor without an ordered host copy.
+        """Chunk rows -> device tensor with one host copy and one transfer.
 
-        Chunk payloads are staged into a preallocated buffer in **arrival
-        order** (one memoryview write per chunk — the only host copy),
-        then the whole buffer moves to the device once and the
-        ``block_gather`` kernel permutes rows into ``chunk_index`` order
-        there. Sub-chunk (trailing-dim) crops happen on the device view.
+        Each chunk payload is written straight into its output row of a
+        preallocated staging buffer (one memoryview write per chunk — the
+        only host copy), then the whole buffer moves to the device once.
+        Sub-chunk (trailing-dim) crops happen on the device view.
         """
         from ...lake import device as lake_device
         shape, chunk_dims, dtype, groups = self._meta(groups)
@@ -164,14 +163,14 @@ class FTSFCodec(Codec):
         if asm.count != len(wanted):
             raise ValueError(
                 f"decode_device: got {asm.count}/{len(wanted)} chunks")
-        rows = asm.gather(use_pallas=use_pallas)
+        rows = asm.gather()
         out = rows.reshape(tuple(out_lead) + tuple(chunk_shape))
         trailing = tuple(slice(lo, hi) for lo, hi in spec[n_lead:])
         if any(sp != (0, d) for sp, d in zip(spec[n_lead:], chunk_shape)):
             out = out[(Ellipsis,) + trailing]
         on_dev = lake_device.is_device_array(out)
         info = lake_device.DeviceReadInfo(
-            path="block_gather" if on_dev else "host_fallback",
+            path="staged" if on_dev else "host_fallback",
             host_staged_bytes=asm.staged_bytes,
             device_bytes=int(np.prod(out.shape)) * np.dtype(dtype).itemsize,
             on_device=on_dev)

@@ -15,7 +15,8 @@ One place decides how every tensor lays out over the mesh:
   axis name, or ``None``. First-divisible-wins: when several dims name the
   same mesh axis, the first whose extent divides the axis size takes it and
   the rest stay replicated (a mesh axis can partition only one dim).
-  Outside a mesh context (single-device tests) it is the identity.
+  Outside a ``jax.set_mesh`` context (single-device runs) it is the
+  identity.
 * ``shard_map_batch(fn, *args)`` — run ``fn`` batch-locally via shard_map
   over the data axes (for ops GSPMD mispartitions, e.g. batched gathers in
   the MoE dispatch). Identity-wrapped when no mesh is active.
@@ -27,7 +28,7 @@ from typing import Any, List, Optional, Sequence, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 MODEL = "model"
 DATA = "data"
@@ -43,16 +44,10 @@ def _path_str(path) -> str:
     return "/".join(part(k) for k in path)
 
 
-def current_mesh() -> Optional[Mesh]:
-    """The ambient ``with mesh:`` context, or None."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is None or m.empty:
-            return None
-        return m
-    except Exception:
-        return None
+def current_mesh() -> Optional[AbstractMesh]:
+    """The ambient ``jax.set_mesh(mesh)`` context, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def batch_axes(mesh: Mesh) -> tuple:
@@ -118,14 +113,12 @@ def shard_map_batch(fn, *args):
     dsize = _axes_size(mesh, axes)
     if dsize <= 1 or any(a.shape[0] % dsize != 0 for a in args):
         return fn(*args)
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple(P(axes, *([None] * (a.ndim - 1))) for a in args)
     out_shapes = jax.eval_shape(fn, *args)
     out_specs = jax.tree.map(
         lambda s: P(axes, *([None] * (len(s.shape) - 1))), out_shapes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------

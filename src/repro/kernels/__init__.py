@@ -5,9 +5,8 @@ ops.py the jit'd public wrappers (TPU: compiled; CPU: ref fallback or
 interpret=True under test), ref.py the pure-jnp oracles.
 """
 from . import ops, ref
-from .ops import (block_gather, block_gather_host, block_norms, block_scatter,
-                  block_topk, coo_scatter, coo_scatter_host, unshuffle,
-                  unshuffle_host)
+from .ops import (block_gather, block_norms, block_scatter, block_topk,
+                  coo_scatter, coo_scatter_host, unshuffle, unshuffle_host)
 
 
 def install_unshuffle_kernel(force: bool = False) -> bool:
@@ -23,6 +22,6 @@ def install_unshuffle_kernel(force: bool = False) -> bool:
 
 install_unshuffle_kernel()
 
-__all__ = ["ops", "ref", "block_gather", "block_gather_host", "block_norms",
-           "block_scatter", "block_topk", "coo_scatter", "coo_scatter_host",
+__all__ = ["ops", "ref", "block_gather", "block_norms", "block_scatter",
+           "block_topk", "coo_scatter", "coo_scatter_host",
            "unshuffle", "unshuffle_host", "install_unshuffle_kernel"]

@@ -24,6 +24,7 @@ from . import ref
 from .block_gather import block_gather as _pl_block_gather
 from .block_norms import block_norms as _pl_block_norms
 from .block_scatter import block_scatter as _pl_block_scatter
+from .coo_scatter import MAX_K as COO_SCATTER_MAX_K
 from .coo_scatter import coo_scatter as _pl_coo_scatter
 from .unshuffle import byte_unshuffle_planes as _pl_unshuffle
 
@@ -90,8 +91,16 @@ def block_norms(bv: jax.Array, use_pallas: Optional[bool] = None) -> jax.Array:
 @partial(jax.jit, static_argnames=("size", "use_pallas"))
 def coo_scatter(flat_idx: jax.Array, values: jax.Array, size: int,
                 use_pallas: Optional[bool] = None) -> jax.Array:
+    """Dense ``(size,)`` buffer from COO pairs.
+
+    The kernel takes float32 and bfloat16 values only, and at most
+    ``COO_SCATTER_MAX_K`` pairs (what Mosaic compiles for a v5e); any
+    other dtype or count runs the jnp reference scatter, still on the
+    device.
+    """
     pallas, interpret = _decide(use_pallas)
-    if pallas:
+    if (pallas and values.dtype in (jnp.float32, jnp.bfloat16)
+            and values.shape[0] <= COO_SCATTER_MAX_K):
         tile = 512 if size >= 512 else max(128, 1 << max(size - 1, 1).bit_length())
         padded = math.ceil(size / tile) * tile
         out = _pl_coo_scatter(flat_idx, values, padded, tile=tile,
@@ -118,18 +127,6 @@ def unshuffle_host(planes: np.ndarray, *,
     """Host-buffer entry point with the ``compression.set_unshuffle_kernel``
     signature: numpy (itemsize, n) uint8 planes in, numpy (n, itemsize) out."""
     return np.asarray(unshuffle(jnp.asarray(planes), use_pallas=use_pallas))
-
-
-def block_gather_host(x: np.ndarray, ids: np.ndarray,
-                      block_shape: Tuple[int, int], *,
-                      use_pallas: Optional[bool] = None) -> jax.Array:
-    """Host-buffer entry point: numpy operand/ids in, device tiles out.
-
-    This is the lake's device-read doorway (``lake/device.py``): the staged
-    chunk buffer never round-trips through a host-side gather.
-    """
-    return block_gather(jnp.asarray(x), jnp.asarray(ids, dtype=jnp.int32),
-                        tuple(block_shape), use_pallas=use_pallas)
 
 
 def coo_scatter_host(flat_idx: np.ndarray, values: np.ndarray, size: int, *,

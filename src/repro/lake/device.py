@@ -5,12 +5,10 @@ Codecs route decoded chunk payloads here instead of materializing a full
 host tensor:
 
 * :class:`ChunkAssembler` — a preallocated ``(n_slots, row_elems)`` staging
-  buffer that chunk frames are written into via ``memoryview`` writes in
-  **arrival order** (one copy off the decode path, no per-chunk
-  intermediates); ``gather()`` then moves the buffer to the device once and
-  reorders it there with the ``block_gather`` Pallas kernel. The only host
-  copy is the staging write itself — never a second, ordered full-tensor
-  copy.
+  buffer that chunk frames are written into via ``memoryview`` writes,
+  each straight into its output row whatever order the pipeline delivers
+  them in; ``gather()`` then moves the buffer to the device with one
+  ``jax.device_put``. The staging write is the only host copy.
 * :func:`scatter_coo` — COO decode straight to a dense *device* buffer via
   the ``coo_scatter`` kernel: indices/values are the only host arrays; the
   dense tensor first exists on the device.
@@ -26,33 +24,26 @@ device path actually runs, so ``import repro.lake`` stays cheap.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import numpy as np
 
-_JAX: Any = None
-_KOPS: Any = None
-_PROBED = False
 
-
+@functools.lru_cache(maxsize=None)
 def _mods() -> Tuple[Any, Any]:
-    """(jax, repro.kernels.ops) or (None, None) — probed once, lazily."""
-    global _JAX, _KOPS, _PROBED
-    if not _PROBED:
-        _PROBED = True
-        try:
-            import jax as _j
+    """(jax, repro.kernels.ops), or (None, None) on a host without jax.
 
-            from ..kernels import ops as _k
-            _JAX, _KOPS = _j, _k
-        except Exception:  # jax absent: every entry point falls back to numpy
-            _JAX, _KOPS = None, None
-    return _JAX, _KOPS
-
-
-def have_jax() -> bool:
-    return _mods()[0] is not None
+    Only a missing jax means "no device": a broken ``repro.kernels``
+    raises here instead of turning every read into a silent numpy path.
+    """
+    try:
+        import jax
+    except ImportError:
+        return None, None
+    from ..kernels import ops
+    return jax, ops
 
 
 def is_device_array(x: Any) -> bool:
@@ -89,8 +80,8 @@ def to_device(arr: np.ndarray) -> Any:
 class DeviceReadInfo:
     """Accounting for one device read, for stats and the zero-copy gate.
 
-    ``path`` names how the tensor reached the device: ``"block_gather"``
-    (chunk staging + device reorder), ``"coo_scatter"`` (sparse pairs
+    ``path`` names how the tensor reached the device: ``"staged"``
+    (ordered chunk staging + one transfer), ``"coo_scatter"`` (sparse pairs
     scattered on device), or ``"host_fallback"`` (host decode then one
     transfer — layouts without a device kernel, or dtypes jax cannot hold).
     ``host_staged_bytes`` is every byte the read materialized on the host
@@ -106,15 +97,13 @@ class DeviceReadInfo:
 
 
 class ChunkAssembler:
-    """Arrival-order chunk staging + on-device reorder.
+    """Ordered chunk staging + one transfer.
 
-    ``add(out_pos, blob)`` writes a chunk payload into the next free
-    staging row via a ``memoryview`` write (chunks land in whatever order
-    the pipeline delivers them); ``gather()`` device-puts the staging
-    buffer once and permutes rows into output order with the
-    ``block_gather`` kernel (one ``(1, row_elems)`` tile per row). Without
-    jax — or for dtypes the device cannot hold bit-exactly — the reorder
-    is a numpy fancy-index instead.
+    ``add(out_pos, blob)`` writes a chunk payload into staging row
+    ``out_pos`` via a ``memoryview`` write, so chunks may arrive in any
+    order and the buffer is already in output order when the last one
+    lands; ``gather()`` device-puts it once. Dtypes the device cannot hold
+    bit-exactly come back as the numpy buffer itself.
     """
 
     def __init__(self, n_slots: int, row_elems: int, dtype: Any):
@@ -123,8 +112,6 @@ class ChunkAssembler:
         self.row_elems = max(1, int(row_elems))
         self._buf = np.empty((self.n_slots, self.row_elems), dtype=self.dtype)
         self._rows = self._buf.view(np.uint8).reshape(self.n_slots, -1)
-        # output position -> staging row, steering the gather
-        self._ids = np.empty(self.n_slots, dtype=np.int32)
         self.count = 0
 
     @property
@@ -132,54 +119,27 @@ class ChunkAssembler:
         return self.count * self._rows.shape[1]
 
     def add(self, out_pos: int, blob: Any) -> None:
-        """Stage one chunk payload destined for output row ``out_pos``."""
-        row = self.count
-        self._rows[row] = np.frombuffer(blob, dtype=np.uint8)
-        self._ids[out_pos] = row
+        """Stage one chunk payload into output row ``out_pos``."""
+        self._rows[out_pos] = np.frombuffer(blob, dtype=np.uint8)
         self.count += 1
 
-    def gather(self, *, use_pallas: Optional[bool] = None) -> Any:
+    def gather(self) -> Any:
         """The ``(n_slots, row_elems)`` array in output order (device when
         possible), transferring the staging buffer exactly once."""
         if self.count != self.n_slots:
             raise ValueError(
                 f"assembled {self.count} of {self.n_slots} chunks")
-        if self.n_slots == 0:
-            return to_device(self._buf)
-        _, kops = _mods()
-        if kops is not None and device_dtype_exact(self.dtype):
-            # complex is not a Pallas-supported element type (and the
-            # interpreter cannot allocate complex outputs); the jnp
-            # reference gather still runs on the device
-            if np.issubdtype(self.dtype, np.complexfloating):
-                use_pallas = False
-            tiles = kops.block_gather_host(self._buf, self._ids,
-                                           (1, self.row_elems),
-                                           use_pallas=use_pallas)
-            # the gather's zero-fill for padding ids promotes bool tiles to
-            # int32 — every id here is valid, so casting back is exact
-            if tiles.dtype != self.dtype:
-                tiles = tiles.astype(self.dtype)
-            return tiles.reshape(self.n_slots, self.row_elems)
-        return self._buf[self._ids]
-
-    def on_device(self) -> bool:
-        """Whether :meth:`gather` will land on a jax device."""
-        return _mods()[1] is not None and device_dtype_exact(self.dtype)
+        return to_device(self._buf)
 
 
 def scatter_coo(flat_idx: np.ndarray, values: np.ndarray, size: int, *,
                 use_pallas: Optional[bool] = None) -> Any:
     """Dense flat ``(size,)`` buffer from COO pairs — on device when the
-    kernels and dtype allow, else a numpy ``np.add.at`` scatter."""
+    dtype allows, else a numpy ``np.add.at`` scatter."""
     size = int(size)
     _, kops = _mods()
     if (kops is not None and size > 0 and size < 2**31
             and device_dtype_exact(values.dtype)):
-        # complex is not a Pallas-supported element type; the jnp
-        # reference scatter still runs on the device
-        if np.issubdtype(np.dtype(values.dtype), np.complexfloating):
-            use_pallas = False
         return kops.coo_scatter_host(flat_idx, values, size,
                                      use_pallas=use_pallas)
     out = np.zeros(size, dtype=values.dtype)

@@ -96,7 +96,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
             cfg = dataclasses.replace(cfg, **kw)
             config_mod._REGISTRY[arch] = cfg
         cell = specs.make_cell(arch, shape, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(cell.fn,
                              in_shardings=cell.in_shardings,
                              out_shardings=cell.out_shardings,

@@ -2,6 +2,8 @@
 
 A FUNCTION, not a module-level constant: importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before any jax init).
+Axes are Auto: the model code places data with sharding constraints, which
+``jax.make_mesh``'s default Explicit axes refuse.
 """
 
 from __future__ import annotations
@@ -9,11 +11,13 @@ from __future__ import annotations
 import jax
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
 def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def make_production_mesh(*, multi_pod: bool = False):
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))

@@ -8,13 +8,19 @@ Loads params from the latest delta-lake checkpoint when one exists
 weights (layout/perf testing). With ``--weights-dir`` the params come
 from a serve-weights store instead, through the snapshot-pinned
 ``store.models(prefix)`` handle (one merged cold-start fetch plan); the
-engine owns that handle and releases its lease on close.
+engine owns that handle and releases its lease on close. Loaded params
+are placed on the device once, before the engine is built.
+
+The default ``granite-3-8b`` takes about 16 GB in bf16, all of one TPU
+v5e chip's HBM, so it does not fit one chip; ``phi3-mini-3.8b`` (about
+7.6 GB) does.
 """
 
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -24,9 +30,14 @@ from ..models import transformer
 from ..models.config import get_arch
 from ..serve import Request, ServeEngine
 from ..train import checkpoint as ckpt_mod, trainer
+from .compile_cache import enable_compile_cache
+
+PROMPT_LEN = 16  # one prompt length, so prefill compiles once
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Tuple[Any, List[Request]]:
+    """Serve ``--requests`` requests; returns the device params the engine
+    served with and the finished requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b")
     ap.add_argument("--reduced", action="store_true")
@@ -49,7 +60,8 @@ def main() -> None:
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -57,7 +69,9 @@ def main() -> None:
     if not cfg.supports_decode:
         raise SystemExit(f"{cfg.name}: no decode step")
 
-    params = transformer.init_params(cfg, jax.random.key(args.seed))
+    key = jax.random.key(args.seed)
+    init = jax.jit(transformer.init_params, static_argnums=0)
+    params = None
     repo = None
     if args.weights_dir:
         from ..core import DeltaTensorStore
@@ -65,11 +79,12 @@ def main() -> None:
                                   "weights")
         repo = wstore.models(args.weights_prefix)
         if repo.exists():
-            params = repo.load(params)
+            params = repo.load(jax.eval_shape(lambda k: init(cfg, k), key))
             print(f"[serve] loaded {repo.stats()['leaves']} param leaves "
                   f"from {args.weights_dir!r} prefix "
                   f"{args.weights_prefix!r} @ v{repo.version}")
         else:
+            params = init(cfg, key)
             repo.save(params)
             print(f"[serve] seeded fresh weights into {args.weights_dir!r} "
                   f"prefix {args.weights_prefix!r}")
@@ -77,8 +92,7 @@ def main() -> None:
         ckpt = ckpt_mod.DeltaCheckpointer(LocalFSObjectStore(args.ckpt_dir),
                                           shards=args.ckpt_shards)
         if ckpt.restore_available():
-            step, state = ckpt.restore(
-                trainer.init_state(cfg, jax.random.key(args.seed)))
+            step, state = ckpt.restore(trainer.init_state(cfg, key))
             params = state.params
             print(f"[serve] restored params from checkpoint step {step}")
             if args.ckpt_gc_keep is not None:
@@ -87,6 +101,11 @@ def main() -> None:
                       f"{gc['pruned_steps']}, reclaimed "
                       f"{gc['bytes_reclaimed']} bytes "
                       f"({gc['files_deleted']} files)")
+    if params is None:
+        params = init(cfg, key)
+    # one host-to-device copy; a host tree would be copied again by every
+    # call of the jitted prefill and decode
+    params = jax.device_put(params)
 
     extra = {}
     if cfg.family == "vlm":
@@ -97,7 +116,7 @@ def main() -> None:
         rng = np.random.default_rng(args.seed)
         reqs = [Request(rid=i,
                         prompt=rng.integers(0, cfg.vocab_size,
-                                            (int(rng.integers(4, 24)),)).astype(np.int32),
+                                            (PROMPT_LEN,)).astype(np.int32),
                         max_new_tokens=args.max_new)
                 for i in range(args.requests)]
         for r in reqs:
@@ -108,6 +127,7 @@ def main() -> None:
         tok = sum(len(r.out_tokens) for r in reqs)
         print(f"[serve] {len(reqs)} requests, {tok} tokens, {dt:.2f}s "
               f"({tok/dt:.1f} tok/s) on {args.slots} slots")
+    return params, reqs
 
 
 if __name__ == "__main__":

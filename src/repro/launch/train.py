@@ -26,6 +26,7 @@ from ..data.synthetic import token_stream
 from ..lake import LocalFSObjectStore
 from ..models.config import get_arch
 from ..train import checkpoint as ckpt_mod, optimizer as opt, trainer
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--n-hosts", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

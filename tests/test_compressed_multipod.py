@@ -30,7 +30,8 @@ cfg = dataclasses.replace(get_arch("granite-3-8b").reduced(),
                           d_model=256, d_ff=512, vocab_size=4096,
                           n_layers=2, head_dim=64)
 ocfg = opt.OptConfig()
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 n_pods = 2
 
 state = jax.eval_shape(lambda: trainer.init_compressed_state(
@@ -47,7 +48,7 @@ b_sh = {k: NamedSharding(mesh, P("pod", "data", None)) for k in batch}
 
 ratio = 0.05
 step = trainer.make_compressed_train_step(cfg, ocfg, ratio=ratio, mesh=mesh)
-with mesh:
+with jax.set_mesh(mesh):
     compiled = jax.jit(step, in_shardings=(pod_first, b_sh)).lower(
         state, batch).compile()
     cost = hlo_cost.analyze(compiled.as_text())

@@ -55,7 +55,7 @@ def test_read_device_full_byte_identical(dtype):
     with store.open("x") as ref:
         out, info = ref.read_device(with_info=True)
         want = ref.read()
-    assert info.path == "block_gather" and info.on_device
+    assert info.path == "staged" and info.on_device
     got = np.asarray(out)
     assert got.dtype == np.dtype(dtype)
     np.testing.assert_array_equal(got, want)
@@ -70,7 +70,7 @@ def test_read_device_slice_byte_identical():
     with store.open("x") as ref:
         out, info = ref.read_device(spec, with_info=True)
         want = ref.read_slice(spec)
-    assert info.path == "block_gather" and info.on_device
+    assert info.path == "staged" and info.on_device
     np.testing.assert_array_equal(np.asarray(out), want)
     # only the 7 wanted chunks were staged on the host, not the full tensor
     assert info.host_staged_bytes == 7 * 3 * 8 * 8 * 4

@@ -23,14 +23,12 @@ from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((4, 4), ("data", "model"))
 cell = specs.make_cell("whisper-tiny", "train_4k", mesh)
-with mesh:
+with jax.set_mesh(mesh):
     jt = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                  out_shardings=cell.out_shardings, donate_argnums=cell.donate)
     lowered = jt.lower(*cell.args)
     compiled = lowered.compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns one dict per device
-        cost = cost[0] if cost else {}
     from repro.analysis import hlo_cost
     c = hlo_cost.analyze(compiled.as_text())
 print(json.dumps({"flops": c.flops, "bytes": c.bytes,
@@ -58,7 +56,8 @@ def test_make_cell_specs_have_shardings():
     import jax
     from repro.launch import specs
     from repro.models.config import list_archs
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     for arch in list_archs():
         cell = specs.make_cell(arch, "train_4k", mesh)
         n_in = len(jax.tree.leaves(cell.in_shardings))

@@ -23,10 +23,7 @@ def test_scan_trip_count_multiplies_flops():
     expected = 10 * 2 * 16 * 32 * 32
     assert abs(c.flops - expected) / expected < 0.01
     # XLA's own analysis undercounts by the trip count
-    xla_cost = comp.cost_analysis()
-    if isinstance(xla_cost, (list, tuple)):  # older jax: one dict per device
-        xla_cost = xla_cost[0] if xla_cost else {}
-    xla = xla_cost.get("flops", 0)
+    xla = comp.cost_analysis().get("flops", 0)
     assert xla < expected / 5
 
 
