@@ -48,7 +48,7 @@ from typing import (TYPE_CHECKING, Any, Dict, Iterator, List, Optional,
 
 import numpy as np
 
-from ..lake import columnar
+from ..lake import columnar, spans
 from ..lake.io import ReadExecutor, content_cache_key
 from ..lake.log import Snapshot
 from ..lake.table import Filters, file_overlaps, filter_rows, physical_path
@@ -557,13 +557,21 @@ class TensorRef:
 
     # -- reads -----------------------------------------------------------------
 
-    def _groups(self, filters: Optional[Filters] = None) -> List[Dict[str, Any]]:
-        """Header + surviving chunk batches, fetched concurrently."""
+    def _adds(self, filters: Optional[Filters] = None) -> List[Dict[str, Any]]:
+        """The chunk files whose stats can hold rows matching ``filters``."""
+        return [a for a in self._entry.chunk_adds if file_overlaps(a, filters)]
+
+    def _fetch(self, adds: List[Dict[str, Any]],
+               filters: Optional[Filters] = None) -> List[Dict[str, Any]]:
+        """Header + the batches of ``adds``, fetched concurrently."""
         table = self._catalog.table_for(self._entry.shard)
-        adds = [a for a in self._entry.chunk_adds if file_overlaps(a, filters)]
         groups: List[Dict[str, Any]] = [self.header]
         groups.extend(table.fetch_adds(adds, filters=filters))
         return groups
+
+    def _groups(self, filters: Optional[Filters] = None) -> List[Dict[str, Any]]:
+        """Header + surviving chunk batches, fetched concurrently."""
+        return self._fetch(self._adds(filters), filters)
 
     def read(self) -> np.ndarray:
         """Full dense read (the paper's read-tensor)."""
@@ -598,20 +606,25 @@ class TensorRef:
         host-decode fallback. ``slices`` matches :meth:`read_slice`;
         ``with_info=True`` additionally returns the
         :class:`~repro.lake.device.DeviceReadInfo` accounting.
+
+        With spans on (:mod:`repro.lake.spans`) the call is one read id;
+        ``store.plan`` covers the slice normalization, the codec's
+        pushdown filters and the pruning of chunk files.
         """
         codec = self.codec
-        if slices is None:
-            out, info = codec.decode_device(self._groups(),
+        if slices is not None and not codec.supports_slice:
+            raise NotImplementedError(
+                f"layout {self.layout!r} does not support slice reads")
+        with spans.new_read():
+            with spans.span("store.plan"):
+                spec = filters = None
+                if slices is not None:
+                    spec = normalize_slices(self.shape,
+                                            [_as_spec_item(s) for s in slices])
+                    filters = codec.slice_filters(self.header, spec) or None
+                adds = self._adds(filters)
+            out, info = codec.decode_device(self._fetch(adds, filters), spec,
                                             use_pallas=use_pallas)
-        else:
-            if not codec.supports_slice:
-                raise NotImplementedError(
-                    f"layout {self.layout!r} does not support slice reads")
-            spec = normalize_slices(self.shape,
-                                    [_as_spec_item(s) for s in slices])
-            filters = codec.slice_filters(self.header, spec)
-            out, info = codec.decode_device(self._groups(filters or None),
-                                            spec, use_pallas=use_pallas)
         if info.on_device:
             self._catalog._store.io.stats.bump(
                 bytes_to_device=info.device_bytes)

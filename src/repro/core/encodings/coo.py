@@ -16,6 +16,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
+from ...lake import spans
 from .base import (Codec, RowGroup, SliceSpec, SparseCOO, as_coo,
                    header_dtype, header_shape, make_header, normalize_slices,
                    register, split_groups)
@@ -96,15 +97,16 @@ class COOCodec(Codec):
         dense buffer directly on the device.
         """
         from ...lake import device as lake_device
-        t = self._coo(groups)
-        if spec is not None:
-            t = t.slice(normalize_slices(t.shape, spec))
-        size = int(np.prod(t.shape)) if t.ndim else 1
-        if t.nnz and t.ndim:
-            flat = np.ravel_multi_index(tuple(t.indices.T), t.shape)
-        else:
-            flat = np.zeros(0, dtype=np.int64)
-        values = np.asarray(t.values)
+        with spans.span("store.stage"):
+            t = self._coo(groups)
+            if spec is not None:
+                t = t.slice(normalize_slices(t.shape, spec))
+            size = int(np.prod(t.shape)) if t.ndim else 1
+            if t.nnz and t.ndim:
+                flat = np.ravel_multi_index(tuple(t.indices.T), t.shape)
+            else:
+                flat = np.zeros(0, dtype=np.int64)
+            values = np.asarray(t.values)
         out = lake_device.scatter_coo(flat, values, size,
                                       use_pallas=use_pallas)
         out = out.reshape(t.shape)

@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
+from ...lake import spans
 from .base import (Codec, RowGroup, SliceSpec, as_dense, first_scalar,
                    header_dtype, header_shape, make_header, normalize_slices,
                    register, slice_shape, split_groups)
@@ -154,12 +155,13 @@ class FTSFCodec(Codec):
                                 indexing="ij")
             flat_idx = np.ravel_multi_index([g.ravel() for g in grids], lead)
             wanted = {int(ci): pos for pos, ci in enumerate(flat_idx)}
-        asm = lake_device.ChunkAssembler(len(wanted), chunk_elems, dtype)
-        for g in groups:
-            for i, blob in zip(np.asarray(g["chunk_index"]), g["chunk"]):
-                pos = wanted.get(int(i))
-                if pos is not None:
-                    asm.add(pos, blob)
+        with spans.span("store.stage"):
+            asm = lake_device.ChunkAssembler(len(wanted), chunk_elems, dtype)
+            for g in groups:
+                for i, blob in zip(np.asarray(g["chunk_index"]), g["chunk"]):
+                    pos = wanted.get(int(i))
+                    if pos is not None:
+                        asm.add(pos, blob)
         if asm.count != len(wanted):
             raise ValueError(
                 f"decode_device: got {asm.count}/{len(wanted)} chunks")

@@ -42,7 +42,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from ..lake import DeltaTable, ObjectStore, ReadExecutor, columnar
+from ..lake import DeltaTable, ObjectStore, ReadExecutor, columnar, spans
 from ..lake.compression import (CompressionSpec, DeltaBase, UnknownCodecError,
                                 parse_compression)
 from ..lake.io import content_cache_key, get_default_executor
@@ -1008,10 +1008,16 @@ class DeltaTensorStore:
              "hedges_launched", "hedges_won",
              "plans", "plan_requests",          # read_many scheduling
              "plan_keys_fetched", "plan_keys_deduped",
+             "deltas_reconstructed",            # variant delta frames
              "decode_s", "decode_overlap_frac", # staged frame decode
              "decodes_offloaded", "bytes_to_device",
+             "fetch_wait_s", "decode_queue_s",  # waits, seconds
              "latency": {"count", "mean_s", "p50_s", "p95_s",
-                         "p99_s", "max_s"}}
+                         "p99_s", "max_s"},
+             "spans": {name: {"count", "total_s", "self_s"}}}
+
+        ``spans`` is the process's span table (:mod:`repro.lake.spans`),
+        empty unless spans were turned on.
         """
         s = self.io.stats
         return {"gets": s.gets, "cache_hits": s.cache_hits,
@@ -1026,7 +1032,10 @@ class DeltaTensorStore:
                 "decode_overlap_frac": s.decode_overlap_frac,
                 "decodes_offloaded": s.decodes_offloaded,
                 "bytes_to_device": s.bytes_to_device,
-                "latency": s.latency.summary()}
+                "fetch_wait_s": s.fetch_wait_s,
+                "decode_queue_s": s.decode_queue_s,
+                "latency": s.latency.summary(),
+                "spans": spans.snapshot()}
 
     def version(self) -> Union[int, Tuple[int, ...]]:
         """Latest version: an int (1-shard) or the per-shard version vector."""

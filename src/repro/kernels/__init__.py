@@ -6,7 +6,7 @@ interpret=True under test), ref.py the pure-jnp oracles.
 """
 from . import ops, ref
 from .ops import (block_gather, block_norms, block_scatter, block_topk,
-                  coo_scatter, coo_scatter_host, unshuffle, unshuffle_host)
+                  coo_scatter, unshuffle, unshuffle_host)
 
 
 def install_unshuffle_kernel(force: bool = False) -> bool:
@@ -23,5 +23,5 @@ def install_unshuffle_kernel(force: bool = False) -> bool:
 install_unshuffle_kernel()
 
 __all__ = ["ops", "ref", "block_gather", "block_norms", "block_scatter",
-           "block_topk", "coo_scatter", "coo_scatter_host",
-           "unshuffle", "unshuffle_host", "install_unshuffle_kernel"]
+           "block_topk", "coo_scatter", "unshuffle", "unshuffle_host",
+           "install_unshuffle_kernel"]

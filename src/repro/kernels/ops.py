@@ -129,15 +129,6 @@ def unshuffle_host(planes: np.ndarray, *,
     return np.asarray(unshuffle(jnp.asarray(planes), use_pallas=use_pallas))
 
 
-def coo_scatter_host(flat_idx: np.ndarray, values: np.ndarray, size: int, *,
-                     use_pallas: Optional[bool] = None) -> jax.Array:
-    """Host-buffer entry point: COO pairs in, dense device buffer out."""
-    if len(flat_idx) == 0:
-        return jnp.zeros((int(size),), dtype=values.dtype)
-    return coo_scatter(jnp.asarray(flat_idx, dtype=jnp.int32),
-                       jnp.asarray(values), int(size), use_pallas=use_pallas)
-
-
 @partial(jax.jit, static_argnames=("block_shape", "k", "use_pallas"))
 def block_topk(x: jax.Array, block_shape: Tuple[int, int], k: int,
                use_pallas: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:

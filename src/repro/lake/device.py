@@ -12,6 +12,8 @@ host tensor:
 * :func:`scatter_coo` — COO decode straight to a dense *device* buffer via
   the ``coo_scatter`` kernel: indices/values are the only host arrays; the
   dense tensor first exists on the device.
+* the ``store.h2d`` span around each transfer call (its host time; it
+  carries ``bytes=``) and ``store.dispatch`` around the kernel's call.
 * :func:`to_device` / :func:`device_dtype_exact` — the jax boundary.
   ``jax.device_put`` silently downcasts 64-bit dtypes unless
   ``jax_enable_x64`` is set, so anything that cannot round-trip bit-exactly
@@ -29,6 +31,8 @@ from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import numpy as np
+
+from . import spans
 
 
 @functools.lru_cache(maxsize=None)
@@ -72,7 +76,8 @@ def to_device(arr: np.ndarray) -> Any:
     """``jax.device_put`` when bit-exact; the numpy array itself otherwise."""
     jx, _ = _mods()
     if jx is not None and device_dtype_exact(arr.dtype):
-        return jx.device_put(arr)
+        with spans.span("store.h2d", bytes=arr.nbytes):
+            return jx.device_put(arr)
     return arr
 
 
@@ -137,11 +142,17 @@ def scatter_coo(flat_idx: np.ndarray, values: np.ndarray, size: int, *,
     """Dense flat ``(size,)`` buffer from COO pairs — on device when the
     dtype allows, else a numpy ``np.add.at`` scatter."""
     size = int(size)
-    _, kops = _mods()
+    jx, kops = _mods()
     if (kops is not None and size > 0 and size < 2**31
             and device_dtype_exact(values.dtype)):
-        return kops.coo_scatter_host(flat_idx, values, size,
-                                     use_pallas=use_pallas)
+        jnp = jx.numpy
+        if len(flat_idx) == 0:
+            return jnp.zeros((size,), dtype=values.dtype)
+        with spans.span("store.h2d", bytes=4 * len(flat_idx) + values.nbytes):
+            idx = jnp.asarray(flat_idx, dtype=jnp.int32)
+            vals = jnp.asarray(values)
+        with spans.span("store.dispatch"):
+            return kops.coo_scatter(idx, vals, size, use_pallas=use_pallas)
     out = np.zeros(size, dtype=values.dtype)
     if len(flat_idx):
         np.add.at(out, flat_idx, values)

@@ -47,6 +47,7 @@ from dataclasses import dataclass, field
 from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
                     Tuple)
 
+from . import spans
 from .compression import decode_frame, frame_info, is_framed
 
 DEFAULT_MAX_WORKERS = 8
@@ -239,6 +240,11 @@ class ReadStats:
     decode_overlap_s: float = 0.0
     decodes_offloaded: int = 0
     bytes_to_device: int = 0
+    # waits, in seconds: a reading thread blocked in fetch_ordered on a
+    # file not yet fetched and decoded, and a frame off the wire waiting
+    # for a decode worker (the bounded handoff included)
+    fetch_wait_s: float = 0.0
+    decode_queue_s: float = 0.0
     # per-request latency histogram (virtual-clock durations on a modeled
     # store, wall-clock otherwise); see LatencyHistogram
     latency: LatencyHistogram = field(default_factory=LatencyHistogram,
@@ -269,6 +275,7 @@ class ReadStats:
             self.decode_s = self.decode_overlap_s = 0.0
             self.decodes_offloaded = 0
             self.bytes_to_device = 0
+            self.fetch_wait_s = self.decode_queue_s = 0.0
         self.latency.reset()
 
 
@@ -490,7 +497,8 @@ class ReadExecutor:
 
     # -- raw gets ------------------------------------------------------------
 
-    def _timed_get(self, store: Any, key: str) -> bytes:
+    def _timed_get(self, store: Any, key: str,
+                   read: Optional[int] = None) -> bytes:
         # one *attempt* = one histogram sample (hedged retries each record
         # their own latency on their own thread). On a modeled store the
         # sample is the deterministic virtual-clock duration of this
@@ -499,7 +507,8 @@ class ReadExecutor:
         with self._inflight_lock:
             self._inflight += 1
         try:
-            data = store.get(key)
+            with spans.span("store.fetch", read=read):
+                data = store.get(key)
         finally:
             with self._inflight_lock:
                 self._inflight -= 1
@@ -514,7 +523,10 @@ class ReadExecutor:
         self.stats.bump(gets=1)
         if self.hedge_after_s is None or self.hedge_attempts <= 1:
             return self._timed_get(store, key)
-        return self.hedged(lambda: self._timed_get(store, key),
+        # hedged attempts run on threads of their own: the read id rides
+        # along as an argument
+        read = spans.current_read()
+        return self.hedged(lambda: self._timed_get(store, key, read),
                            hedge_after_s=self.hedge_after_s,
                            attempts=self.hedge_attempts)
 
@@ -567,12 +579,14 @@ class ReadExecutor:
 
     def _fetch_miss(self, store: Any, key: str,
                     cache_key: Optional[Tuple[int, str]],
-                    partition: Optional[str] = None) -> bytes:
+                    partition: Optional[str] = None,
+                    read: Optional[int] = None) -> bytes:
         # inline path (decode stage disabled): fetch and decode on the same
         # I/O thread, decode serializing ahead of this thread's next fetch
-        raw = self._get_raw(store, key)
-        data = self._decode_timed(store, raw, partition,
-                                  self._virtual_done(store))
+        with spans.in_read(read):
+            raw = self._get_raw(store, key)
+            data = self._decode_timed(store, raw, partition,
+                                      self._virtual_done(store))
         if cache_key is not None:
             self.cache.put(cache_key, data, partition)
         return data
@@ -598,14 +612,15 @@ class ReadExecutor:
         store — charged onto the virtual timeline via ``charge_compute``
         (starting no earlier than ``ready``, the fetch's virtual
         completion), so ``elapsed_s`` reports the pipelined makespan while
-        ``io_elapsed_s`` keeps the pure wire time.
+        ``io_elapsed_s`` keeps the pure wire time. The ``store.decode``
+        span and ``decode_s`` take their time from one timer.
         """
         if not is_framed(raw):
             return raw
-        t0 = time.perf_counter()
         overlapped = self._inflight > 0
-        data = self._decode_wire(store, raw, partition=partition)
-        d = time.perf_counter() - t0
+        with spans.timed("store.decode") as timer:
+            data = self._decode_wire(store, raw, partition=partition)
+        d = timer.seconds
         overlapped = overlapped or self._inflight > 0
         self.stats.bump(decode_s=d, decode_overlap_s=d if overlapped else 0.0)
         lm = getattr(store, "latency", None)
@@ -618,22 +633,28 @@ class ReadExecutor:
     def _submit_miss(self, store: Any, key: str,
                      cache_key: Optional[Tuple[int, str]],
                      partition: Optional[str]) -> Future:
-        """Submit one cache-miss fetch; decode rides the staged pool."""
+        """Submit one cache-miss fetch; decode rides the staged pool.
+
+        The submitting thread's read id goes along as an argument.
+        """
+        read = spans.current_read()
         if self._decode is None:
             return self._io.submit(self._fetch_miss, store, key, cache_key,
-                                   partition)
+                                   partition, read)
         out: Future = Future()
         self._io.submit(self._wire_stage, store, key, cache_key, partition,
-                        out)
+                        out, read)
         return out
 
     def _wire_stage(self, store: Any, key: str,
                     cache_key: Optional[Tuple[int, str]],
-                    partition: Optional[str], out: Future) -> None:
+                    partition: Optional[str], out: Future,
+                    read: Optional[int] = None) -> None:
         if not out.set_running_or_notify_cancel():
             return
         try:
-            raw = self._get_raw(store, key)
+            with spans.in_read(read):
+                raw = self._get_raw(store, key)
         except BaseException as e:
             out.set_exception(e)
             return
@@ -644,19 +665,23 @@ class ReadExecutor:
             out.set_result(raw)
             return
         ready = self._virtual_done(store)
+        queued = time.perf_counter()
         # bounded handoff: when decoders fall behind, the fetch thread
         # blocks here instead of buffering unbounded frames
         self._decode_slots.acquire()
         self.stats.bump(decodes_offloaded=1)
         self._decode.submit(self._decode_stage, store, raw, cache_key,
-                            partition, ready, out)
+                            partition, ready, out, queued, read)
 
     def _decode_stage(self, store: Any, raw: bytes,
                       cache_key: Optional[Tuple[int, str]],
                       partition: Optional[str], ready: Optional[float],
-                      out: Future) -> None:
+                      out: Future, queued: float,
+                      read: Optional[int] = None) -> None:
         try:
-            data = self._decode_timed(store, raw, partition, ready)
+            self.stats.bump(decode_queue_s=time.perf_counter() - queued)
+            with spans.in_read(read):
+                data = self._decode_timed(store, raw, partition, ready)
             if cache_key is not None:
                 self.cache.put(cache_key, data, partition)
             out.set_result(data)
@@ -736,7 +761,10 @@ class ReadExecutor:
             for i in range(len(keys)):
                 if i + window < len(keys):
                     pending.append(submit(i + window))
-                yield pending[i].result()
+                t0 = time.perf_counter()
+                data = pending[i].result()
+                self.stats.bump(fetch_wait_s=time.perf_counter() - t0)
+                yield data
         finally:
             for f in pending:
                 f.cancel()

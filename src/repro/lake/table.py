@@ -25,7 +25,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Set, Tuple, Un
 
 import numpy as np
 
-from . import columnar
+from . import columnar, spans
 from .compression import (CompressionSpec, DeltaBase, encode_frame,
                           parse_compression)
 from .io import (ReadExecutor, content_cache_key, get_default_executor,
@@ -568,8 +568,10 @@ class DeltaTable:
                  if add.get("contentHash") else None for add in adds]
         for data in self.io.fetch_ordered(self.store, keys,
                                           cache_names=names):
-            batch = columnar.read_table(data, columns)
-            yield _apply_mask(batch, _row_mask(batch, filters))
+            with spans.span("store.parse"):
+                batch = columnar.read_table(data, columns)
+                batch = _apply_mask(batch, _row_mask(batch, filters))
+            yield batch
 
     def scan(self, columns: Optional[Sequence[str]] = None, *,
              filters: Optional[Filters] = None,

@@ -228,7 +228,8 @@ def test_decode_stats_surface_in_io_stats():
     store.get("x")
     s = store.io_stats()
     for key in ("decode_s", "decode_overlap_frac", "decodes_offloaded",
-                "bytes_to_device"):
+                "bytes_to_device", "deltas_reconstructed", "fetch_wait_s",
+                "decode_queue_s", "spans"):
         assert key in s
     assert s["decode_s"] > 0.0
     assert 0.0 <= s["decode_overlap_frac"] <= 1.0
