@@ -13,7 +13,7 @@ One process, three phases, each checked against a plain numpy reference:
    ``(183, 24, 1140, 1717)`` with 0.038% of its cells drawn (colliding
    draws sum, which leaves about 1.5 M non-zeros), three ``X[i]`` slices
    scattered densely into HBM; plus a small COO tensor of random float32
-   values, which the kernel must reproduce bit for bit;
+   values, which the device scatter must reproduce bit for bit;
 3. serve: ``phi3-mini-3.8b`` at full width with random weights, saved
    through ``store.models(prefix)`` and served by ``repro.launch.serve``
    from that store.
@@ -46,7 +46,6 @@ from repro.configs.paper_store import PAPER_STORE  # noqa: E402
 from repro.core import DeltaTensorStore  # noqa: E402
 from repro.core.encodings.base import SparseCOO  # noqa: E402
 from repro.data.synthetic import ffhq_like, uber_like  # noqa: E402
-from repro.kernels.ops import COO_SCATTER_MAX_K  # noqa: E402
 from repro.lake import LocalFSObjectStore, compression  # noqa: E402
 from repro.launch import serve  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -157,7 +156,6 @@ def phase_sparse(root: str, *, seed: int,
         check(np.array_equal(np.asarray(out), want.to_dense()),
               f"sparse: X[{i}] differs from SparseCOO.slice().to_dense()")
         reads.append({"i": i, "nnz": want.nnz, "read_device_s": read_s,
-                      "kernel": want.nnz <= COO_SCATTER_MAX_K,
                       "device_bytes": info.device_bytes,
                       "host_staged_bytes": info.host_staged_bytes})
         del out

@@ -594,8 +594,7 @@ class TensorRef:
         return codec.decode_slice(self._groups(filters or None), spec)
 
     def read_device(self, slices: Optional[Sequence] = None, *,
-                    with_info: bool = False,
-                    use_pallas: Optional[bool] = None):
+                    with_info: bool = False):
         """Read straight into a jax device buffer (numpy when jax can't).
 
         FTSF reads stage chunk payloads into output order and transfer
@@ -623,8 +622,7 @@ class TensorRef:
                                             [_as_spec_item(s) for s in slices])
                     filters = codec.slice_filters(self.header, spec) or None
                 adds = self._adds(filters)
-            out, info = codec.decode_device(self._fetch(adds, filters), spec,
-                                            use_pallas=use_pallas)
+            out, info = codec.decode_device(self._fetch(adds, filters), spec)
         if info.on_device:
             self._catalog._store.io.stats.bump(
                 bytes_to_device=info.device_bytes)

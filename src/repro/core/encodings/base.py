@@ -196,8 +196,7 @@ class Codec:
         raise NotImplementedError
 
     def decode_device(self, groups: List[Dict[str, Any]],
-                      spec: Optional[SliceSpec] = None, *,
-                      use_pallas: Optional[bool] = None):
+                      spec: Optional[SliceSpec] = None):
         """Decode onto an accelerator device: ``(array, DeviceReadInfo)``.
 
         The base implementation is the documented fallback — host decode

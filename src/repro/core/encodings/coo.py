@@ -90,11 +90,11 @@ class COOCodec(Codec):
         return t.slice(normalize_slices(t.shape, spec)).to_dense()
 
     def decode_device(self, groups: List[Dict[str, Any]],
-                      spec: SliceSpec = None, *, use_pallas=None):
+                      spec: SliceSpec = None):
         """COO rows -> dense device tensor; the dense array never exists
         on the host. Only the (nnz, ndim) indices and (nnz,) values are
-        staged; the ``coo_scatter`` kernel materializes the zeros-filled
-        dense buffer directly on the device.
+        staged; ``coo_scatter`` materializes the zeros-filled dense
+        tensor directly on the device, in its own shape.
         """
         from ...lake import device as lake_device
         with spans.span("store.stage"):
@@ -107,9 +107,7 @@ class COOCodec(Codec):
             else:
                 flat = np.zeros(0, dtype=np.int64)
             values = np.asarray(t.values)
-        out = lake_device.scatter_coo(flat, values, size,
-                                      use_pallas=use_pallas)
-        out = out.reshape(t.shape)
+        out = lake_device.scatter_coo(flat, values, t.shape)
         info = lake_device.DeviceReadInfo(
             path="coo_scatter",
             host_staged_bytes=int(t.indices.nbytes + values.nbytes),

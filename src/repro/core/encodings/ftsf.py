@@ -131,7 +131,7 @@ class FTSFCodec(Codec):
         return out[(Ellipsis,) + trailing] if trailing else out
 
     def decode_device(self, groups: List[Dict[str, Any]],
-                      spec: SliceSpec = None, *, use_pallas=None):
+                      spec: SliceSpec = None):
         """Chunk rows -> device tensor with one host copy and one transfer.
 
         Each chunk payload is written straight into its output row of a

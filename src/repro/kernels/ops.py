@@ -4,6 +4,7 @@ Dispatch policy: compiled Pallas on TPU; on CPU the default is the ref.py
 oracle (bit-identical semantics, fast under XLA:CPU), while
 ``use_pallas=True`` forces the kernel through the Pallas interpreter —
 that is how the test suite validates the kernel bodies on this machine.
+``coo_scatter`` has no kernel: XLA's own scatter runs on every backend.
 
 All wrappers pad operands to kernel alignment (tile multiples) and crop
 the result, so callers never see the alignment constraints.
@@ -24,8 +25,6 @@ from . import ref
 from .block_gather import block_gather as _pl_block_gather
 from .block_norms import block_norms as _pl_block_norms
 from .block_scatter import block_scatter as _pl_block_scatter
-from .coo_scatter import MAX_K as COO_SCATTER_MAX_K
-from .coo_scatter import coo_scatter as _pl_coo_scatter
 from .unshuffle import byte_unshuffle_planes as _pl_unshuffle
 
 
@@ -88,25 +87,35 @@ def block_norms(bv: jax.Array, use_pallas: Optional[bool] = None) -> jax.Array:
     return ref.block_norms(bv)
 
 
-@partial(jax.jit, static_argnames=("size", "use_pallas"))
-def coo_scatter(flat_idx: jax.Array, values: jax.Array, size: int,
-                use_pallas: Optional[bool] = None) -> jax.Array:
-    """Dense ``(size,)`` buffer from COO pairs.
+@partial(jax.jit, static_argnames=("shape",))
+def coo_scatter(flat_idx: jax.Array, values: jax.Array,
+                shape: Tuple[int, ...]) -> jax.Array:
+    """Dense array of ``shape`` from COO pairs whose indices are row-major
+    flat offsets: XLA's scatter-add into zeros, on every backend.
 
-    The kernel takes float32 and bfloat16 values only, and at most
-    ``COO_SCATTER_MAX_K`` pairs (what Mosaic compiles for a v5e); any
-    other dtype or count runs the jnp reference scatter, still on the
-    device.
+    Bit for bit ``ref.coo_scatter(flat_idx, values, size).reshape(shape)``:
+    out-of-range indices drop (``coo_encode`` pads with ``size``),
+    negative ones count once from the end, duplicates accumulate, the
+    dtype is kept. Work is O(size + K). Unravelling the indices here,
+    rather than reshaping a flat result, lets XLA lay the result out in
+    one pass: on a TPU v5e an Uber ``X[i]`` (1, 24, 1140, 1717) float32
+    from 16,430-16,966 pairs took 2.3 ms, against 3.9 ms for the flat
+    scatter and its reshape, and compiled in 0.4-0.6 s a K. A sorted,
+    block-windowed Pallas kernel ran the flat scatter in 0.54 ms against
+    XLA's 1.8 ms, but compiled for 15 s a K, and each new slice shape
+    compiles inside a read.
     """
-    pallas, interpret = _decide(use_pallas)
-    if (pallas and values.dtype in (jnp.float32, jnp.bfloat16)
-            and values.shape[0] <= COO_SCATTER_MAX_K):
-        tile = 512 if size >= 512 else max(128, 1 << max(size - 1, 1).bit_length())
-        padded = math.ceil(size / tile) * tile
-        out = _pl_coo_scatter(flat_idx, values, padded, tile=tile,
-                              interpret=interpret)
-        return out[:size]
-    return ref.coo_scatter(flat_idx, values, size)
+    size = math.prod(shape)
+    idx = jnp.where(flat_idx < 0, flat_idx + size, flat_idx)
+    # anything out of range unravels past the leading dimension and drops
+    idx = jnp.where((idx >= 0) & (idx < size), idx, size)
+    coords = []
+    for dim in reversed(shape[1:]):
+        coords.append(idx % dim)
+        idx = idx // dim
+    coords.append(idx)
+    out = jnp.zeros(shape, dtype=values.dtype)
+    return out.at[tuple(reversed(coords))].add(values, mode="drop")
 
 
 @partial(jax.jit, static_argnames=("use_pallas",))

@@ -10,10 +10,10 @@ host tensor:
   them in; ``gather()`` then moves the buffer to the device with one
   ``jax.device_put``. The staging write is the only host copy.
 * :func:`scatter_coo` — COO decode straight to a dense *device* buffer via
-  the ``coo_scatter`` kernel: indices/values are the only host arrays; the
-  dense tensor first exists on the device.
+  the jitted ``coo_scatter`` (XLA's scatter-add): indices/values are the
+  only host arrays; the dense tensor first exists on the device.
 * the ``store.h2d`` span around each transfer call (its host time; it
-  carries ``bytes=``) and ``store.dispatch`` around the kernel's call.
+  carries ``bytes=``) and ``store.dispatch`` around the scatter's call.
 * :func:`to_device` / :func:`device_dtype_exact` — the jax boundary.
   ``jax.device_put`` silently downcasts 64-bit dtypes unless
   ``jax_enable_x64`` is set, so anything that cannot round-trip bit-exactly
@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, Tuple
 
 import numpy as np
 
@@ -137,23 +137,25 @@ class ChunkAssembler:
         return to_device(self._buf)
 
 
-def scatter_coo(flat_idx: np.ndarray, values: np.ndarray, size: int, *,
-                use_pallas: Optional[bool] = None) -> Any:
-    """Dense flat ``(size,)`` buffer from COO pairs — on device when the
-    dtype allows, else a numpy ``np.add.at`` scatter."""
-    size = int(size)
+def scatter_coo(flat_idx: np.ndarray, values: np.ndarray,
+                shape: Tuple[int, ...]) -> Any:
+    """Dense array of ``shape`` from COO pairs with row-major flat indices
+    — on device when the dtype allows, else a numpy ``np.add.at``
+    scatter."""
+    shape = tuple(int(d) for d in shape)
+    size = int(np.prod(shape))
     jx, kops = _mods()
     if (kops is not None and size > 0 and size < 2**31
             and device_dtype_exact(values.dtype)):
         jnp = jx.numpy
         if len(flat_idx) == 0:
-            return jnp.zeros((size,), dtype=values.dtype)
+            return jnp.zeros(shape, dtype=values.dtype)
         with spans.span("store.h2d", bytes=4 * len(flat_idx) + values.nbytes):
             idx = jnp.asarray(flat_idx, dtype=jnp.int32)
             vals = jnp.asarray(values)
         with spans.span("store.dispatch"):
-            return kops.coo_scatter(idx, vals, size, use_pallas=use_pallas)
+            return kops.coo_scatter(idx, vals, shape)
     out = np.zeros(size, dtype=values.dtype)
     if len(flat_idx):
         np.add.at(out, flat_idx, values)
-    return out
+    return out.reshape(shape)

@@ -36,7 +36,8 @@ def test_sparse_phase_tiny(smoke, tmp_path):
                              nnz_ratio=0.01, probe=(8, 64))
     assert res["path"] == "coo_scatter"
     assert [r["i"] for r in res["reads"]] == [0, 3, 5]
-    assert all(r["kernel"] for r in res["reads"])
+    # each X[i] lands whole in HBM: one float32 (4, 30, 40) slice
+    assert all(r["device_bytes"] == 4 * 30 * 40 * 4 for r in res["reads"])
     assert res["nnz"] > 0 and res["probe_nnz"] == 5
 
 

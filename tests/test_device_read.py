@@ -188,13 +188,17 @@ def test_chunk_assembler_incomplete_raises():
 
 def test_scatter_coo_empty_and_dense():
     out = device.scatter_coo(np.empty(0, np.int64),
-                             np.empty(0, np.float32), 8)
+                             np.empty(0, np.float32), (8,))
     np.testing.assert_array_equal(np.asarray(out), np.zeros(8, np.float32))
     out = device.scatter_coo(np.array([1, 5]),
-                             np.array([2.0, 3.0], np.float32), 6)
+                             np.array([2.0, 3.0], np.float32), (6,))
     want = np.zeros(6, np.float32)
     want[[1, 5]] = [2.0, 3.0]
     np.testing.assert_array_equal(np.asarray(out), want)
+    # flat indices land row-major in an N-D result of the asked shape
+    out = device.scatter_coo(np.array([1, 5]),
+                             np.array([2.0, 3.0], np.float32), (2, 3))
+    np.testing.assert_array_equal(np.asarray(out), want.reshape(2, 3))
 
 
 # ---------------------------------------------------------------------------

@@ -66,22 +66,81 @@ def test_block_norms_matches_ref(g, b, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
 
 
-@pytest.mark.parametrize("size,k", [(512, 17), (1024, 100), (640, 1), (130, 9)])
+def _coo_dense(idx, vals, size):
+    """numpy oracle of a COO scatter-add: pairs applied one at a time in
+    their order (each add rounded to the dtype), negative indices counted
+    from the end, out-of-range ones dropped."""
+    idx = np.asarray(idx, np.int64)
+    vals = np.asarray(vals)
+    keep = (idx >= -size) & (idx < size)
+    out = np.zeros(size, vals.dtype)
+    np.add.at(out, idx[keep], vals[keep])
+    return out
+
+
+def _assert_coo_exact(idx, vals, shape):
+    size = int(np.prod(shape))
+    got = np.asarray(ops.coo_scatter(idx, vals, shape))
+    assert got.dtype == np.asarray(vals).dtype and got.shape == shape
+    want = _coo_dense(idx, vals, size).reshape(shape)
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    flat = np.asarray(ref.coo_scatter(idx, vals, size)).reshape(shape)
+    np.testing.assert_array_equal(got.view(np.uint8), flat.view(np.uint8))
+
+
+@pytest.mark.parametrize("size,k", [(512, 17), (1024, 100), (640, 1), (130, 9),
+                                    (4099, 300),       # not a multiple of 128 or 1024
+                                    (65536, 30000)])   # K far above an Uber day's 20,002
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_coo_scatter_matches_ref(size, k, dtype):
+    # distinct indices in random, unsorted order
     idx = jnp.asarray(RNG.choice(size, size=k, replace=False), jnp.int32)
     vals = _mk((k,), dtype, seed=5)
-    got = ops.coo_scatter(idx, vals, size, use_pallas=True)
-    want = ref.coo_scatter(idx, vals, size)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_coo_exact(idx, vals, (size,))
 
 
-def test_coo_scatter_padding_indices_drop():
-    idx = jnp.asarray([5, 700, 1000], jnp.int32)  # 700/1000 out of range
-    vals = jnp.asarray([1.0, 2.0, 3.0], jnp.float32)
-    out = ops.coo_scatter(idx, vals, 512, use_pallas=True)
-    assert float(out[5]) == 1.0
-    assert float(jnp.sum(out)) == 1.0
+def _coo_case(name, rng):
+    """(idx, values as float64, shape) for one structure of COO pairs."""
+    if name == "padding":
+        # coo_encode pads with index == size; beyond it and below -size drop
+        # too, and -1 counts from the end as in jnp indexing
+        idx = np.array([5, 512, 700, 1000, -1, -513, 2**31 - 1, 511])
+        return idx, rng.standard_normal(len(idx)), (512,)
+    if name == "duplicates":
+        # 40 pairs on 7 indices: each index accumulates its pairs in order
+        idx = rng.integers(100, 107, 40)
+        return idx, rng.standard_normal(40), (1000,)
+    if name == "hub_and_empty_tiles":
+        # one hot region of 256 cells holds 5000 pairs, a few far ones
+        # hit other tiles, and most tiles of the buffer stay empty
+        idx = np.concatenate([rng.integers(20_000, 20_256, 5000),
+                              rng.choice(1 << 18, 50, replace=False)])
+        return rng.permutation(idx), rng.standard_normal(len(idx)), (1 << 18,)
+    if name == "descending":
+        idx = np.arange(5000)[::-1] * 7
+        return idx, rng.standard_normal(len(idx)), (35_003,)
+    if name == "specials":
+        # inf, -inf, NaN and -0.0 keep their bits; other cells stay +0.0
+        idx = np.array([0, 1, 2, 3, 127, 128])
+        vals = np.array([np.inf, -np.inf, np.nan, -0.0, 1e-30, -2.5])
+        return idx, vals, (129,)
+    if name == "nd_slice":
+        # an X[i] of a 4-D tensor, ragged trailing dims, padded with size:
+        # flat indices land row-major in the N-D result
+        shape = (1, 6, 13, 17)
+        size = int(np.prod(shape))
+        idx = np.concatenate([rng.choice(size, 400, replace=False),
+                              [size, size, -1, -size - 1, 5, 5]])
+        return idx, rng.standard_normal(len(idx)), shape
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", ["padding", "duplicates", "hub_and_empty_tiles",
+                                  "descending", "specials", "nd_slice"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_coo_scatter_padding_indices_drop(case, dtype):
+    idx, vals, shape = _coo_case(case, np.random.default_rng(11))
+    _assert_coo_exact(jnp.asarray(idx, jnp.int32), jnp.asarray(vals, dtype), shape)
 
 
 def test_block_topk_matches_ref():
