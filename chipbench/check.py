@@ -8,8 +8,8 @@ bytes it was given, so every number here has the limit 0:
 * ``mismatched_elements``: elements whose bits differ from the reference
   (a result of the wrong shape or dtype counts every element);
 * ``failed_reads``: reads that raised or never came;
-* ``off_device_reads``: kept reads whose result is not a jax array on the
-  chip the cell runs on;
+* ``off_device_reads``: kept reads whose result (every leaf, where it is
+  a tree of arrays) is not a jax array on the chip the cell runs on;
 * ``clients_without_reads``: clients that finished no read in the window.
 
 With ``control=True`` each kept result is replaced by the reference in the
@@ -25,7 +25,7 @@ import jax
 import numpy as np
 
 from .kinds import Built
-from .loops.closed import Window
+from .loops import Window
 
 LIMITS = {"mismatched_elements": 0, "failed_reads": 0, "off_device_reads": 0,
           "clients_without_reads": 0}
@@ -40,14 +40,28 @@ def mismatched(got: np.ndarray, want: np.ndarray) -> int:
     return int(np.count_nonzero((a != b).any(axis=1)))
 
 
+def mismatched_tree(got: Any, want: Any) -> int:
+    """:func:`mismatched` summed over the leaves of two trees of arrays (one
+    array is a tree of one leaf); trees of another structure differ in
+    every element."""
+    g, g_def = jax.tree_util.tree_flatten(got)
+    w, w_def = jax.tree_util.tree_flatten(want)
+    if g_def != w_def:
+        return sum(int(np.size(x)) for x in w)
+    return sum(mismatched(np.asarray(a), np.asarray(b)) for a, b in zip(g, w))
+
+
 def host_copies(window: Window, device: Any) -> List[Dict[str, Any]]:
-    """Bring the kept reads to the host and drop their device buffers."""
+    """Bring the kept reads (arrays or trees of them) to the host and drop
+    their device buffers."""
     out = []
     for client, spec, result, info in window.kept:
-        on_device = (isinstance(result, jax.Array)
-                     and result.devices() == {device})
+        leaves = jax.tree_util.tree_leaves(result)
+        on_device = bool(leaves) and all(
+            isinstance(x, jax.Array) and x.devices() == {device}
+            for x in leaves)
         out.append({"client": client, "spec": spec, "on_device": on_device,
-                    "host": np.asarray(result)})
+                    "host": jax.tree_util.tree_map(np.asarray, result)})
     window.kept.clear()
     return out
 
@@ -59,7 +73,7 @@ def compare(built: Built, window: Window, kept: List[Dict[str, Any]], *,
     for k in kept:
         want = built.reference(k["spec"])
         got = built.control(want) if control else k["host"]
-        bad += mismatched(got, want)
+        bad += mismatched_tree(got, want)
         off += 0 if k["on_device"] else 1
     values = {"mismatched_elements": bad, "failed_reads": window.failed,
               "off_device_reads": off,

@@ -9,7 +9,7 @@ writes the store from the seed through the program and returns a
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -25,7 +25,11 @@ class Built:
     ``control(answer)`` is that answer in the nearest precision below the
     configuration's. ``kernel_bytes(spec)`` is the least HBM traffic of the
     read's device work, for a roofline (0 where the read runs no kernel,
-    None where it cannot be counted).
+    None where it cannot be counted). ``shape`` is what the kind's loop
+    needs of the tensor (a tree of tensors may give ``()``).
+    ``counters()``, where the kind gives it, returns counters of its own
+    that ``ReadStats`` does not hold (a gateway's loads, say); the harness
+    puts their window deltas into the record's ``counters``.
     """
 
     store: Any
@@ -35,6 +39,7 @@ class Built:
     reference: Callable[[Spec], np.ndarray]
     control: Callable[[np.ndarray], np.ndarray]
     kernel_bytes: Callable[[Spec], Optional[int]]
+    counters: Optional[Callable[[], Dict[str, float]]] = None
 
 
 def full_spec(shape: Sequence[int], spec: Spec) -> Spec:

@@ -1,6 +1,8 @@
-"""The one traffic generator: slice requests from a mix's parameter file.
+"""The closed loop's traffic generator: slice requests from a mix's
+parameter file.
 
-A mix (``chipbench/traffic/<name>.json``) gives
+A closed-loop mix (``chipbench/traffic/<name>.json``, checked by
+``loops.closed.check``) gives
 
 * ``clients``: closed-loop clients, each waiting for its read before the
   next;
@@ -20,22 +22,11 @@ slices in another order.
 from __future__ import annotations
 
 import itertools
-import json
 from typing import Any, Dict, Iterator, List, Sequence
 
 import numpy as np
 
 from .kinds import Spec
-
-
-def load(path: str) -> Dict[str, Any]:
-    """Read and check a mix's parameter file."""
-    with open(path) as f:
-        mix = json.load(f)
-    for key in ("clients", "slice", "warmup", "check_per_client"):
-        if key not in mix:
-            raise ValueError(f"{path}: no {key!r}")
-    return mix
 
 
 def starts(mix: Dict[str, Any], shape: Sequence[int]) -> List[Spec]:

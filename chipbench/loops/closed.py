@@ -11,45 +11,26 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import nullcontext
-from dataclasses import dataclass, field
-from typing import Any, Callable, ContextManager, List, Optional, Tuple
+from typing import Any, Callable, ContextManager, List, Tuple
 
 import jax
 import numpy as np
 
 from .. import loadgen
 from ..kinds import Built, Spec
+from . import Window
 
 # a read still in flight this long past the deadline has failed
 GRACE_S = 60.0
+# the keys of a closed-loop mix (see loadgen)
+KEYS = ("clients", "slice", "warmup", "check_per_client")
 
 
-@dataclass
-class Window:
-    """What the window did, for the metrics and the check."""
-
-    start: float = 0.0
-    end: float = 0.0
-    latencies: List[float] = field(default_factory=list)
-    bytes: int = 0
-    attempted: int = 0
-    failed: int = 0
-    errors: List[str] = field(default_factory=list)
-    kernel_bytes: List[Optional[int]] = field(default_factory=list)
-    # (client, spec, result, DeviceReadInfo) of the reads the check keeps
-    kept: List[Tuple[int, Spec, Any, Any]] = field(default_factory=list)
-    clients_without_reads: int = 0
-    lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    @property
-    def seconds(self) -> float:
-        """Length of the window."""
-        return self.end - self.start
-
-    @property
-    def reads(self) -> int:
-        """Reads completed."""
-        return len(self.latencies)
+def check(mix: dict) -> None:
+    """Raise ``ValueError`` where the mix lacks a closed-loop key."""
+    for key in KEYS:
+        if key not in mix:
+            raise ValueError(f"closed-loop mix: no {key!r}")
 
 
 def read_once(built: Built, spec: Spec) -> Tuple[Any, Any]:

@@ -9,7 +9,8 @@ store from the seed under ``<checkout>/.chipbench``, warms the cell's
 shapes, runs the traffic for ``--seconds`` and checks the reads it kept.
 The last line of standard output is the result as one JSON object; with
 ``--trace 1`` its metrics are the per-layer ones, read from a profiler
-trace of the window.
+trace of the window and from the store's spans, which only a traced run
+turns on.
 """
 
 import time

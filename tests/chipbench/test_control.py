@@ -89,3 +89,15 @@ def test_mismatched_counts_bits_shape_and_dtype():
     assert check.mismatched(a.reshape(3, 2), a) == 6
     assert check.passed({"x": {"value": 0, "limit": 0}})
     assert not check.passed({"x": {"value": 1, "limit": 0}})
+
+
+def test_mismatched_tree_sums_leaves_and_counts_a_changed_structure():
+    a = {"w": np.zeros((2, 3), np.float32), "b": np.ones(4, np.float32)}
+    b = {k: v.copy() for k, v in a.items()}
+    assert check.mismatched_tree(a, b) == 0
+    b["b"][0] = 2
+    b["w"][1, 1] = 1
+    assert check.mismatched_tree(a, b) == 2
+    assert check.mismatched_tree({"w": a["w"]}, a) == 10
+    assert check.mismatched_tree(a["w"], a["w"].copy()) == 0
+    assert check.mismatched_tree(a["b"], b["b"]) == 1

@@ -6,11 +6,13 @@ import os
 import subprocess
 import sys
 import time
+import types
 
 import jax
 import pytest
 
 from chipbench import harness, loadgen
+from chipbench.loops import closed as closed_loop
 
 from .conftest import ROOT
 
@@ -21,9 +23,11 @@ def test_every_cell_resolves_by_name(bench):
             w, cfg, mix, metrics = harness.cell(bench, wl["name"], trace)
             assert w is wl and cfg["name"] == wl["config"]
             importlib.import_module(f"chipbench.kinds.{cfg['kind']}")
-            importlib.import_module(f"chipbench.loops.{mix['loop']}")
-            shape = cfg.get("shape") or [cfg["rows"], *cfg["row_shape"]]
-            assert loadgen.starts(mix, shape)
+            loop = importlib.import_module(f"chipbench.loops.{mix['loop']}")
+            assert harness.loop_of(mix) is loop
+            loop.check(mix)
+            for fn in ("warm", "run"):
+                assert callable(getattr(loop, fn))
             assert metrics
             for m in metrics:
                 mod = importlib.import_module(f"chipbench.metrics.{m['name']}")
@@ -31,6 +35,21 @@ def test_every_cell_resolves_by_name(bench):
         names = [m["name"] for m in bench["end_to_end"]
                  if wl["name"] in m.get("workloads", [wl["name"]])]
         assert "setup_s" in names and len(names) >= 2
+
+
+def test_closed_loop_cells_have_decks(bench):
+    closed = 0
+    for wl in bench["workloads"]:
+        _, cfg, mix, _ = harness.cell(bench, wl["name"], False)
+        if mix["loop"] != "closed":
+            continue
+        closed += 1
+        shape = cfg.get("shape") or [cfg["rows"], *cfg["row_shape"]]
+        assert loadgen.starts(mix, shape)
+        for key in closed_loop.KEYS:
+            with pytest.raises(ValueError, match=key):
+                closed_loop.check({k: v for k, v in mix.items() if k != key})
+    assert closed
 
 
 def test_config_files_match_benchmark(bench):
@@ -111,3 +130,43 @@ def test_run_fails_in_a_directory_of_only_the_benchmark(tmp_path):
                         "--trace", "0"], cwd=tmp_path, env=env,
                        capture_output=True, text=True, timeout=120)
     assert p.returncode != 0 and p.stdout.strip() == ""
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_the_record_carries_every_counter_and_traced_spans(
+        monkeypatch, bench, tmp_path, tiny_dense, trace):
+    from repro.lake import spans
+
+    seen = []
+    spy = types.ModuleType("chipbench.metrics.spy_record")
+    spy.read = lambda rec: seen.append(rec)
+    monkeypatch.setitem(sys.modules, spy.__name__, spy)
+    cfg, mix = tiny_dense
+    before = spans.snapshot()
+    out = harness.run(cfg, mix, [{"name": "spy_record", "unit": "count"}],
+                      seed=2**31 + 11, seconds=0.3, trace=trace,
+                      started=time.perf_counter(), device=jax.devices()[0],
+                      peaks={"hbm_bytes_per_s": 819e9}, work=str(tmp_path))
+    assert out["correct"], out["checks"]
+    (rec,) = seen
+    io = rec["io"]
+    for key in ("gets", "cache_hits", "cache_misses", "frames_decoded",
+                "frame_bytes_wire", "frame_bytes_decoded", "decode_s",
+                "fetch_wait_s", "decode_queue_s", "bytes_to_device"):
+        assert key in io
+    assert "latency" not in io and "_lock" not in io
+    assert io["cache_hits"] + io["cache_misses"] > 0
+    assert io["bytes_to_device"] >= rec["bytes"] > 0
+    assert rec["counters"] == {}
+    if trace:
+        parse = rec["spans"]["store.parse"]
+        assert parse["count"] > 0 and 0 < parse["self_s"] <= parse["total_s"]
+        assert {"store.stage", "store.h2d"} <= set(rec["spans"])
+        ranked = out["breakdown"]["idle_by_span"]
+        assert ranked
+        assert {n for n, _ in ranked} <= {"any"} | set(rec["spans"])
+        assert spans.span("store.parse") is spans.span("store.stage")
+    else:
+        assert rec["spans"] is None
+        assert spans.snapshot() == before
+        assert "breakdown" not in out
