@@ -1009,6 +1009,7 @@ class DeltaTensorStore:
              "plans", "plan_requests",          # read_many scheduling
              "plan_keys_fetched", "plan_keys_deduped",
              "deltas_reconstructed",            # variant delta frames
+             "delta_base_bytes",                # base bytes XORed into them
              "decode_s", "decode_overlap_frac", # staged frame decode
              "decodes_offloaded", "bytes_to_device",
              "fetch_wait_s", "decode_queue_s",  # waits, seconds
@@ -1028,6 +1029,7 @@ class DeltaTensorStore:
                 "plan_keys_fetched": s.plan_keys_fetched,
                 "plan_keys_deduped": s.plan_keys_deduped,
                 "deltas_reconstructed": s.deltas_reconstructed,
+                "delta_base_bytes": s.delta_base_bytes,
                 "decode_s": s.decode_s,
                 "decode_overlap_frac": s.decode_overlap_frac,
                 "decodes_offloaded": s.decodes_offloaded,

@@ -48,8 +48,10 @@ bytes of an already-stored base object — and XORs the new bytes against it
 *before* shuffle + codec, recording ``delta_base`` (the base's absolute
 object key) and ``delta_base_hash`` in the header. A fine-tuned variant
 that perturbs a few percent of a base tensor XORs to long zero runs that
-any byte codec crushes. :func:`decode_frame` reverses this given a
-``base_fetch`` callback supplying the base's decoded bytes.
+any byte codec crushes. The read path (``lake/io.py``
+``ReadExecutor``) reverses this: :func:`unframe` gives the XOR residue and
+:func:`byte_undelta` XORs the base's decoded bytes back in.
+:func:`decode_frame` takes plain frames only.
 """
 
 from __future__ import annotations
@@ -444,23 +446,10 @@ def encode_frame(raw: bytes, spec: CompressionSpec, *, itemsize: int = 1,
                                   level=spec.level).id
 
 
-def decode_frame(data: bytes, *,
-                 base_fetch: Optional[Callable[[str, Optional[str]],
-                                               bytes]] = None) -> bytes:
-    """Undo :func:`encode_frame`; unframed bytes pass through untouched.
-
-    This passthrough IS the back-compat contract: every pre-compression
-    file (parq-lite ``PQL1``, JSON logs, spilled indexes) flows through
-    the same read path unchanged, byte for byte.
-
-    Delta frames need ``base_fetch(base_key, base_hash) -> bytes``
-    supplying the base object's *decoded* bytes; decoding a delta frame
-    without one raises ``ValueError`` (the payload alone is an XOR
-    residue, not data).
-    """
-    info = frame_info(data)
-    if info is None:
-        return data
+def unframe(data: Buffer, info: Dict[str, Any]) -> Buffer:
+    """The payload of the frame ``data`` (whose header is ``info``),
+    decompressed and unshuffled: the raw bytes, or a delta frame's XOR
+    residue, which :func:`byte_undelta` turns back into data."""
     (hlen,) = struct.unpack_from("<I", data, 4)
     payload = data[8 + hlen:]
     body = get_compressor(info["codec"]).decompress(payload)
@@ -470,12 +459,25 @@ def decode_frame(data: bytes, *,
         raise ValueError(
             f"frame decode size mismatch: got {len(body)} bytes, header "
             f"says {info['raw_size']}")
-    base_key = info.get("delta_base")
-    if base_key is not None:
-        if base_fetch is None:
-            raise ValueError(
-                f"delta frame references base {base_key!r}; decoding "
-                f"requires a base_fetch callback")
-        body = byte_undelta(body, base_fetch(base_key,
-                                             info.get("delta_base_hash")))
     return body
+
+
+def decode_frame(data: bytes) -> bytes:
+    """Undo :func:`encode_frame`; unframed bytes pass through untouched.
+
+    This passthrough IS the back-compat contract: every pre-compression
+    file (parq-lite ``PQL1``, JSON logs, spilled indexes) flows through
+    the same read path unchanged, byte for byte.
+
+    A delta frame raises ``ValueError``: its payload alone is an XOR
+    residue, not data, and only the read path, which can fetch the base,
+    reconstructs it.
+    """
+    info = frame_info(data)
+    if info is None:
+        return data
+    if info.get("delta_base") is not None:
+        raise ValueError(
+            f"delta frame references base {info['delta_base']!r}; "
+            f"only the read path reconstructs it")
+    return unframe(data, info)

@@ -48,7 +48,7 @@ from typing import (Any, Callable, Dict, Iterator, List, Optional, Sequence,
                     Tuple)
 
 from . import spans
-from .compression import decode_frame, frame_info, is_framed
+from .compression import byte_undelta, frame_info, is_framed, unframe
 
 DEFAULT_MAX_WORKERS = 8
 DEFAULT_CACHE_BYTES = 64 << 20
@@ -222,8 +222,10 @@ class ReadStats:
     frames_decoded: int = 0
     frame_bytes_wire: int = 0
     frame_bytes_decoded: int = 0
-    # variant delta frames reconstructed against their base object
+    # variant delta frames reconstructed against their base object, and
+    # the base bytes XORed into them
     deltas_reconstructed: int = 0
+    delta_base_bytes: int = 0
     # read_many fetch scheduling: merged plans built, requests they
     # covered, unique keys actually fetched, and references that were
     # deduplicated away (a shared chunk key counted once per extra
@@ -269,7 +271,7 @@ class ReadStats:
             self.hedges_launched = self.hedges_won = 0
             self.frames_decoded = 0
             self.frame_bytes_wire = self.frame_bytes_decoded = 0
-            self.deltas_reconstructed = 0
+            self.deltas_reconstructed = self.delta_base_bytes = 0
             self.plans = self.plan_requests = 0
             self.plan_keys_fetched = self.plan_keys_deduped = 0
             self.decode_s = self.decode_overlap_s = 0.0
@@ -538,22 +540,29 @@ class ReadExecutor:
         # additionally reconstruct against their base object (fetched
         # inline on this thread — never re-submitted to the I/O pool, so a
         # saturated pool cannot deadlock on its own dependencies).
+        # The store.undelta span covers the base's fetch (a cache hit, or a
+        # nested store.fetch and store.decode) and the XOR: its self time
+        # is the XOR and the cache lookup.
         info = frame_info(data)
         if info is None:
             return data
-        if info.get("delta_base") is not None:
-            if depth >= MAX_DELTA_DEPTH:
-                raise ValueError(
-                    f"delta base chain deeper than {MAX_DELTA_DEPTH}")
-            self.stats.bump(deltas_reconstructed=1)
+        base_key = info.get("delta_base")
+        if base_key is not None and depth >= MAX_DELTA_DEPTH:
+            raise ValueError(
+                f"delta base chain deeper than {MAX_DELTA_DEPTH}")
         wire = len(data)
-        data = decode_frame(
-            data,
-            base_fetch=lambda bk, bh: self._base_bytes(store, bk, bh,
-                                                       depth + 1, partition))
+        body = unframe(data, info)
+        if base_key is not None:
+            with spans.span("store.undelta", bytes=len(body)):
+                base = self._base_bytes(store, base_key,
+                                        info.get("delta_base_hash"),
+                                        depth + 1, partition)
+                body = byte_undelta(body, base)
+            self.stats.bump(deltas_reconstructed=1,
+                            delta_base_bytes=min(len(body), len(base)))
         self.stats.bump(frames_decoded=1, frame_bytes_wire=wire,
-                        frame_bytes_decoded=len(data))
-        return data
+                        frame_bytes_decoded=len(body))
+        return body
 
     def _base_bytes(self, store: Any, key: str,
                     content_hash: Optional[str] = None,
@@ -561,7 +570,8 @@ class ReadExecutor:
                     partition: Optional[str] = None) -> bytes:
         # decoded bytes of a delta frame's base: content-hash-named cache
         # lookup first (shared with dedup'd reads of the base itself),
-        # then a plain inline get + decode
+        # then a plain inline get + decode, under a store.decode span of
+        # its own (decode_s already counts it, inside the delta's decode)
         ck: Optional[Tuple[int, str]] = None
         if self.cache.capacity:
             name = content_cache_key(content_hash) if content_hash else key
@@ -571,8 +581,9 @@ class ReadExecutor:
                 self.stats.bump(cache_hits=1)
                 return hit
             self.stats.bump(cache_misses=1)
-        data = self._decode_wire(store, self._get_raw(store, key), depth,
-                                 partition)
+        raw = self._get_raw(store, key)
+        with spans.span("store.decode"):
+            data = self._decode_wire(store, raw, depth, partition)
         if ck is not None:
             self.cache.put(ck, data, partition)
         return data

@@ -7,9 +7,10 @@ Loads params from the latest delta-lake checkpoint when one exists
 (elastic: any mesh/host count can restore), else serves fresh-initialized
 weights (layout/perf testing). With ``--weights-dir`` the params come
 from a serve-weights store instead, through the snapshot-pinned
-``store.models(prefix)`` handle (one merged cold-start fetch plan); the
-engine owns that handle and releases its lease on close. Loaded params
-are placed on the device once, before the engine is built.
+``store.models(prefix)`` handle (one merged cold-start fetch plan, each
+leaf landing in device memory); the engine owns that handle and releases
+its lease on close. Restored checkpoint params are placed on the device
+once, before the engine is built.
 
 The default ``granite-3-8b`` takes about 16 GB in bf16, all of one TPU
 v5e chip's HBM, so it does not fit one chip; ``phi3-mini-3.8b`` (about
@@ -79,7 +80,8 @@ def main(argv: Optional[List[str]] = None) -> Tuple[Any, List[Request]]:
                                   "weights")
         repo = wstore.models(args.weights_prefix)
         if repo.exists():
-            params = repo.load(jax.eval_shape(lambda k: init(cfg, k), key))
+            params = repo.load(jax.eval_shape(lambda k: init(cfg, k), key),
+                               device=True)
             print(f"[serve] loaded {repo.stats()['leaves']} param leaves "
                   f"from {args.weights_dir!r} prefix "
                   f"{args.weights_prefix!r} @ v{repo.version}")
@@ -93,7 +95,9 @@ def main(argv: Optional[List[str]] = None) -> Tuple[Any, List[Request]]:
                                           shards=args.ckpt_shards)
         if ckpt.restore_available():
             step, state = ckpt.restore(trainer.init_state(cfg, key))
-            params = state.params
+            # one host-to-device copy; a host tree would be copied again
+            # by every call of the jitted prefill and decode
+            params = jax.device_put(state.params)
             print(f"[serve] restored params from checkpoint step {step}")
             if args.ckpt_gc_keep is not None:
                 gc = ckpt.gc(keep=args.ckpt_gc_keep)
@@ -103,9 +107,6 @@ def main(argv: Optional[List[str]] = None) -> Tuple[Any, List[Request]]:
                       f"({gc['files_deleted']} files)")
     if params is None:
         params = init(cfg, key)
-    # one host-to-device copy; a host tree would be copied again by every
-    # call of the jitted prefill and decode
-    params = jax.device_put(params)
 
     extra = {}
     if cfg.family == "vlm":

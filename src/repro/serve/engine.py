@@ -126,10 +126,10 @@ class ServeEngine:
     @classmethod
     def from_repo(cls, repo: ModelRepo, template: Any, cfg: ArchConfig, *,
                   n_slots: int, max_len: int, **kwargs) -> "ServeEngine":
-        """Build an engine whose weights load from ``repo`` (one merged
-        fetch plan); the engine owns the handle and releases its snapshot
-        lease on ``close()``."""
-        params = repo.load(template)
+        """Build an engine whose weights load from ``repo`` into device
+        memory (one merged fetch plan); the engine owns the handle and
+        releases its snapshot lease on ``close()``."""
+        params = repo.load(template, device=True)
         return cls(params, cfg, n_slots=n_slots, max_len=max_len,
                    repo=repo, **kwargs)
 

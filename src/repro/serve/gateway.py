@@ -12,7 +12,7 @@ survivable:
   tenant cannot starve the others and each tenant's share tracks its
   weight;
 * **cold-start coalescing** — concurrent ``load_model`` calls for the
-  same ``(prefix, version)`` share ONE single-flight
+  same ``(prefix, version, device)`` share ONE single-flight
   :meth:`~repro.serve.repo.ModelRepo.load` (one merged ``read_many``
   plan, the delta-variant base chunks fetched once); the flight key pins
   the resolved version vector, so two tenants joining one flight get
@@ -43,6 +43,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..core.store import DeltaTensorStore, VersionArg
+from ..lake import spans
 from ..lake.io import LatencyHistogram
 from .repo import ModelRepo
 
@@ -192,12 +193,14 @@ class Gateway:
                 store.io.cache.add_partition(name, int(spec))
         self._lock = threading.RLock()
         self._tenants: Dict[str, _TenantState] = {}
-        self._flights: Dict[Tuple[str, Tuple[int, ...]], Future] = {}
+        self._flights: Dict[Tuple[str, Tuple[int, ...], bool], Future] = {}
         self._vtime = 0.0
         self._inflight = 0
         self._closed = False
         self._flights_created = 0
         self._coalesced_total = 0
+        self._loads_run = 0
+        self._queue_wait_s = 0.0
         self._pool = ThreadPoolExecutor(max_workers=self.max_inflight,
                                         thread_name_prefix="gateway")
         self._finalizer = weakref.finalize(self, self._pool.shutdown, False)
@@ -304,6 +307,7 @@ class Gateway:
             job = best.queue.popleft()
             best.inflight += 1
             self._inflight += 1
+            self._queue_wait_s += self.clock() - job.t_enqueue
             self._vtime = max(self._vtime, job.stag)
             self._pool.submit(self._run, best, job)
 
@@ -337,6 +341,7 @@ class Gateway:
     # -- serving verbs ---------------------------------------------------------
 
     def load_model(self, tenant: str, prefix: str, template: Any, *,
+                   device: bool = False,
                    version: VersionArg = None) -> "Future[Any]":
         """Cold-start a model: coalesced, admission-controlled weight load.
 
@@ -349,13 +354,17 @@ class Gateway:
         generation, byte-identical, even if a re-save lands mid-flight.
         A save that commits *before* a later call resolves simply maps
         that call to a new key: fresh flight, fresh weights. Blocks land
-        in the calling tenant's cache partition.
+        in the calling tenant's cache partition. ``device=True`` loads
+        into device memory (``ModelRepo.load(device=True)``); it is part
+        of the flight key, so a host load and a device load of one model
+        never share a flight. Each flight's load runs inside a
+        ``gateway.load`` span.
         """
         with self._lock:
             if self._closed:
                 raise RuntimeError("gateway is closed")
             vector = self.store.catalog(version).version_vector
-            key = (prefix, vector)
+            key = (prefix, vector, bool(device))
             flight = self._flights.get(key)
             if flight is not None:
                 st = self._tenant(tenant)
@@ -365,8 +374,12 @@ class Gateway:
             part = self._tenant(tenant).policy.cache_partition
 
             def do_load() -> Any:
-                with ModelRepo(self.store, prefix, version=vector) as repo:
-                    return repo.load(template, cache_partition=part)
+                with self._lock:
+                    self._loads_run += 1
+                with spans.span("gateway.load"), \
+                        ModelRepo(self.store, prefix, version=vector) as repo:
+                    return repo.load(template, device=device,
+                                     cache_partition=part)
 
             fut = self.submit(tenant, do_load, cost=self.load_cost)
             self._flights[key] = fut
@@ -374,7 +387,7 @@ class Gateway:
         fut.add_done_callback(lambda _f: self._drop_flight(key))
         return fut
 
-    def _drop_flight(self, key: Tuple[str, Tuple[int, ...]]) -> None:
+    def _drop_flight(self, key: Tuple[str, Tuple[int, ...], bool]) -> None:
         with self._lock:
             self._flights.pop(key, None)
 
@@ -414,13 +427,20 @@ class Gateway:
                     for name, st in self._tenants.items()}
 
     def stats(self) -> Dict[str, Any]:
-        """Gateway-wide counters: flights, coalescing, inflight, shed."""
+        """Gateway-wide counters: flights, coalescing, inflight, shed.
+
+        ``loads_run`` counts flights whose load started; ``queue_wait_s``
+        sums, over every dispatched job, the time from its submission to
+        its dispatch (on the gateway's clock).
+        """
         with self._lock:
             return {"tenants": len(self._tenants),
                     "inflight": self._inflight,
                     "max_inflight": self.max_inflight,
                     "flights_created": self._flights_created,
                     "coalesced_hits": self._coalesced_total,
+                    "loads_run": self._loads_run,
+                    "queue_wait_s": self._queue_wait_s,
                     "open_flights": len(self._flights),
                     "rejected": sum(st.rejected
                                     for st in self._tenants.values()),

@@ -18,7 +18,8 @@ prefix, nothing was pinned between calls, and the delta-variant write path
 * ``repo.load(template)`` reads the whole tree through ONE merged
   :meth:`~repro.core.catalog.Catalog.read_many` fetch plan against the
   pinned catalog — deduplicated chunk keys, per-leaf decode overlapping
-  in-flight fetches.
+  in-flight fetches; ``load(template, device=True)`` lands every leaf in
+  device memory.
 * ``repo.open_variant(name)`` returns a repo for ``<prefix>~<name>``
   whose ``save`` stores each leaf via
   :meth:`~repro.core.batch.WriteBatch.put_variant` against this repo's
@@ -189,20 +190,26 @@ class ModelRepo:
         reqs = [(self._tid(_leaf_name(p)), None) for p, _ in flat]
         return reqs, treedef, [leaf for _, leaf in flat]
 
-    def load(self, template: Any, *, version: "VersionArg" = None,
+    def load(self, template: Any, *, device: bool = False,
+             version: "VersionArg" = None,
              io: Optional[ReadExecutor] = None,
              cache_partition: Optional[str] = None) -> Any:
         """Load the weight tree shaped/typed like ``template``.
 
         ``template`` (e.g. ``jax.eval_shape`` of the init function, or a
-        real params pytree) supplies tree structure and leaf dtypes. The
-        whole tree loads through ONE merged fetch plan against the
-        repo's pinned catalog — a consistent generation even if a
-        re-save lands mid-load. ``version=`` reads a different pinned
-        snapshot (time travel) without re-pinning the repo; ``io=``
-        overrides the store's shared executor; ``cache_partition``
-        routes the fetched blocks into that block-cache priority class
-        (the gateway pins hot base models into a protected partition).
+        real params pytree) supplies tree structure and leaf dtypes; a
+        leaf stored in another dtype raises ``TypeError`` (it is never
+        cast). The whole tree loads through ONE merged fetch plan against
+        the repo's pinned catalog — a consistent generation even if a
+        re-save lands mid-load. ``device=True`` lands each leaf in
+        device memory as a ``jax.Array`` (the plan's
+        ``read_many(device=True)``: chunks staged and transferred once a
+        leaf, no host copy of the whole tree); otherwise leaves are numpy
+        arrays. ``version=`` reads a different pinned snapshot (time
+        travel) without re-pinning the repo; ``io=`` overrides the
+        store's shared executor; ``cache_partition`` routes the fetched
+        blocks into that block-cache priority class (the gateway pins hot
+        base models into a protected partition).
         """
         catalog = (self._catalog if version is None
                    else self.store.catalog(version))
@@ -210,11 +217,14 @@ class ModelRepo:
             raise KeyError(f"no weights saved under prefix "
                            f"{self.prefix!r} (empty store)")
         reqs, treedef, leaves = self._requests(template)
-        arrays = catalog.read_many(reqs, io=io,
+        arrays = catalog.read_many(reqs, io=io, device=device,
                                    cache_partition=cache_partition)
-        out = [arr.astype(np.dtype(leaf.dtype), copy=False)
-               for arr, leaf in zip(arrays, leaves)]
-        return jax.tree_util.tree_unflatten(treedef, out)
+        for (tid, _), arr, leaf in zip(reqs, arrays, leaves):
+            if np.dtype(arr.dtype) != np.dtype(leaf.dtype):
+                raise TypeError(f"{tid} is stored as {np.dtype(arr.dtype)}, "
+                                f"the template asks for "
+                                f"{np.dtype(leaf.dtype)}")
+        return jax.tree_util.tree_unflatten(treedef, arrays)
 
     def stats(self) -> Dict[str, Any]:
         """Pinned-snapshot inventory: leaf count and stored bytes."""

@@ -20,8 +20,9 @@ from repro.lake import (InMemoryObjectStore, LatencyModel, LocalFSObjectStore,
                         ReadExecutor, available_codecs, decode_frame,
                         encode_frame, frame_info, parse_compression,
                         register_compressor)
-from repro.lake.compression import (CompressionSpec, UnknownCodecError,
-                                    byte_shuffle, byte_unshuffle)
+from repro.lake.compression import (CompressionSpec, DeltaBase,
+                                    UnknownCodecError, byte_shuffle,
+                                    byte_undelta, byte_unshuffle, unframe)
 from repro.launch import gc as gc_cli
 
 # an identity codec that never shrinks anything: the deterministic way to
@@ -99,6 +100,20 @@ def test_frame_passthrough_unframed():
     for blob in (b"", b"PQL1junk", b'{"json": true}', bytes(100)):
         assert decode_frame(blob) == blob
         assert frame_info(blob) is None
+
+
+def test_delta_frame_unframes_to_its_residue_and_decode_frame_refuses_it():
+    base = compressible(4096).tobytes()
+    raw = bytearray(base)
+    raw[100:108] = bytes(8)
+    frame, _ = encode_frame(bytes(raw), parse_compression("zlib+shuffle"),
+                            itemsize=4,
+                            delta_base=DeltaBase("t/base.pq", base, "h"))
+    info = frame_info(frame)
+    assert info["delta_base"] == "t/base.pq"
+    assert byte_undelta(unframe(frame, info), base) == bytes(raw)
+    with pytest.raises(ValueError, match="delta frame"):
+        decode_frame(frame)
 
 
 def test_frame_incompressible_falls_back_to_raw_unframed():
