@@ -48,10 +48,11 @@ from typing import (TYPE_CHECKING, Any, Dict, Iterator, List, Optional,
 
 import numpy as np
 
-from ..lake import columnar, spans
+from ..lake import spans
 from ..lake.io import ReadExecutor, content_cache_key
 from ..lake.log import Snapshot
-from ..lake.table import Filters, file_overlaps, filter_rows, physical_path
+from ..lake.table import (Filters, file_overlaps, filter_rows, parse_file,
+                          physical_path)
 from .encodings.base import (SparseCOO, get_codec, header_dtype,
                              header_shape, normalize_slices)
 
@@ -317,7 +318,8 @@ class Catalog:
                     base_keys.setdefault(
                         db, content_cache_key(bh) if bh else None)
             reqs.append(PlanRequest(tid=tid, codec=codec, spec=spec,
-                                    filters=filters, keys=keys))
+                                    filters=filters, keys=keys,
+                                    columns=codec.chunk_columns(header)))
         seen: Dict[str, None] = {}
         total = 0
         for r in reqs:
@@ -344,8 +346,9 @@ class Catalog:
         The plan's unique keys stream through the shared executor's
         windowed :meth:`~repro.lake.io.ReadExecutor.fetch_ordered`, so
         decode of file *k* overlaps the wire fetch of files > *k*; each
-        arriving file is decoded ONCE and handed to every request that
-        wanted it (with that request's own row filters), and a request's
+        arriving file is decoded ONCE, for the columns its requests'
+        codecs read, and handed to every request that wanted it (with
+        that request's own row filters), and a request's
         final codec decode runs as soon as its last file lands — not
         after the whole plan drains. Results come back in request order.
 
@@ -402,7 +405,10 @@ class Catalog:
                 waiters = waiting.get(key, ())
                 if not waiters:
                     continue  # base-object prefetch: block-cached for deltas
-                batch = columnar.read_table(data)
+                wanting = [plan.requests[i] for i in waiters]
+                batch = parse_file(data, _projection(wanting),
+                                   [c for r in wanting for c in r.filters or ()],
+                                   io.stats)
                 for i in waiters:
                     r = plan.requests[i]
                     received[i][key] = filter_rows(batch, r.filters)
@@ -422,6 +428,7 @@ class PlanRequest:
     spec: Optional[List[Tuple[int, int]]]     # normalized; None = full read
     filters: Optional[Filters]                # row-level pushdown predicate
     keys: List[str]                           # full object keys, add order
+    columns: Optional[Sequence[str]] = None   # codec's projection; None = all
 
     @property
     def n_keys(self) -> int:
@@ -444,6 +451,14 @@ class FetchPlan:
     def n_fetches(self) -> int:
         """Object gets this plan will issue."""
         return len(self.unique_keys)
+
+
+def _projection(requests: Sequence[PlanRequest]) -> Optional[List[str]]:
+    """The columns a file shared by ``requests`` is parsed for: the union
+    of their codecs' projections, or every column if one wants all."""
+    if any(r.columns is None for r in requests):
+        return None
+    return list(dict.fromkeys(c for r in requests for c in r.columns))
 
 
 def _as_spec_item(x: Any) -> Optional[Tuple[int, int]]:
@@ -565,8 +580,10 @@ class TensorRef:
                filters: Optional[Filters] = None) -> List[Dict[str, Any]]:
         """Header + the batches of ``adds``, fetched concurrently."""
         table = self._catalog.table_for(self._entry.shard)
-        groups: List[Dict[str, Any]] = [self.header]
-        groups.extend(table.fetch_adds(adds, filters=filters))
+        header = self.header
+        groups: List[Dict[str, Any]] = [header]
+        groups.extend(table.fetch_adds(adds, self.codec.chunk_columns(header),
+                                       filters=filters))
         return groups
 
     def _groups(self, filters: Optional[Filters] = None) -> List[Dict[str, Any]]:

@@ -187,6 +187,14 @@ class Codec:
         """Decoded row groups -> the dense tensor."""
         raise NotImplementedError
 
+    def chunk_columns(self, header: Dict[str, Any]) -> Optional[Sequence[str]]:
+        """The chunk columns every decode of this codec reads.
+
+        Readers parse only these (plus the columns their row filters
+        test); ``None`` parses every column.
+        """
+        return None
+
     def slice_filters(self, header: Dict[str, Any], spec: SliceSpec) -> Dict[str, Tuple[int, int]]:
         """Pushdown predicate {column: (lo, hi)} selecting needed chunk rows."""
         return {}

@@ -67,6 +67,12 @@ class COOCodec(Codec):
                              np.zeros(0, header_dtype(header)), shape)
         return SparseCOO(np.concatenate(idx_parts), np.concatenate(val_parts), shape)
 
+    def chunk_columns(self, header: Dict[str, Any]) -> List[str]:
+        """What :meth:`_coo` reads: the per-row ``dense_shape`` is not
+        among them (the shape comes from the header)."""
+        ndim = len(header_shape(header))
+        return ["nnz_index", "value", *(f"idx{d}" for d in range(ndim))]
+
     def decode(self, groups: List[Dict[str, Any]]) -> np.ndarray:
         """Decoded row groups -> the dense tensor."""
         return self._coo(groups).to_dense()

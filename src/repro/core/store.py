@@ -1013,6 +1013,7 @@ class DeltaTensorStore:
              "decode_s", "decode_overlap_frac", # staged frame decode
              "decodes_offloaded", "bytes_to_device",
              "fetch_wait_s", "decode_queue_s",  # waits, seconds
+             "parse_columns_skipped",           # blocks left unparsed
              "latency": {"count", "mean_s", "p50_s", "p95_s",
                          "p99_s", "max_s"},
              "spans": {name: {"count", "total_s", "self_s"}}}
@@ -1036,6 +1037,7 @@ class DeltaTensorStore:
                 "bytes_to_device": s.bytes_to_device,
                 "fetch_wait_s": s.fetch_wait_s,
                 "decode_queue_s": s.decode_queue_s,
+                "parse_columns_skipped": s.parse_columns_skipped,
                 "latency": s.latency.summary(),
                 "spans": spans.snapshot()}
 

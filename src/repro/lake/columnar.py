@@ -292,17 +292,29 @@ def _header(data: bytes) -> Tuple[Dict[str, Any], int]:
 
 def read_table(data: bytes, columns: Optional[Sequence[str]] = None) -> Dict[str, Any]:
     """Decode a parq-lite file; optionally project a subset of columns."""
+    return read_projected(data, columns)[0]
+
+
+def read_projected(data: bytes, columns: Optional[Sequence[str]] = None,
+                   optional: Sequence[str] = ()) -> Tuple[Dict[str, Any], int]:
+    """:func:`read_table` plus the columns of ``optional`` the file holds.
+
+    Returns the decoded columns and the number of column blocks left
+    out, which are neither decompressed nor decoded. A missing column of
+    ``columns`` raises ``KeyError``; a missing one of ``optional`` does
+    not.
+    """
     header, base = _header(data)
-    want = set(columns) if columns is not None else None
+    want = set(columns) | set(optional) if columns is not None else None
     out: Dict[str, Any] = {}
     for meta in header["columns"]:
         if want is not None and meta["name"] not in want:
             continue
         raw = data[base + meta["offset"]: base + meta["offset"] + meta["length"]]
         out[meta["name"]] = _decode_column(raw, meta)
-    if want is not None and want - set(out):
-        raise KeyError(f"missing columns: {sorted(want - set(out))}")
-    return out
+    if columns is not None and set(columns) - set(out):
+        raise KeyError(f"missing columns: {sorted(set(columns) - set(out))}")
+    return out, len(header["columns"]) - len(out)
 
 
 def num_rows(data: bytes) -> int:

@@ -247,6 +247,8 @@ class ReadStats:
     # for a decode worker (the bounded handoff included)
     fetch_wait_s: float = 0.0
     decode_queue_s: float = 0.0
+    # file parse: column blocks a codec's projection left unparsed
+    parse_columns_skipped: int = 0
     # per-request latency histogram (virtual-clock durations on a modeled
     # store, wall-clock otherwise); see LatencyHistogram
     latency: LatencyHistogram = field(default_factory=LatencyHistogram,
@@ -278,6 +280,7 @@ class ReadStats:
             self.decodes_offloaded = 0
             self.bytes_to_device = 0
             self.fetch_wait_s = self.decode_queue_s = 0.0
+            self.parse_columns_skipped = 0
         self.latency.reset()
 
 

@@ -28,8 +28,8 @@ import numpy as np
 from . import columnar, spans
 from .compression import (CompressionSpec, DeltaBase, encode_frame,
                           parse_compression)
-from .io import (ReadExecutor, content_cache_key, get_default_executor,
-                 store_scope)
+from .io import (ReadExecutor, ReadStats, content_cache_key,
+                 get_default_executor, store_scope)
 from .log import (CommitConflict, DeltaLog, Snapshot, catalog_index_version)
 from .object_store import ObjectNotFoundError, ObjectStore
 
@@ -237,6 +237,21 @@ def filter_rows(batch: Dict[str, Any],
     the batch unchanged, so sharing the dict across requests stays safe.
     """
     return _apply_mask(batch, _row_mask(batch, filters))
+
+
+def parse_file(data: bytes, columns: Optional[Sequence[str]],
+               filter_columns: Sequence[str], stats: ReadStats
+               ) -> Dict[str, Any]:
+    """Parse one data file for ``columns`` (every column when None).
+
+    A projection also takes those of ``filter_columns`` the file holds, so
+    a row mask always sees what it tests. The blocks left out are neither
+    decompressed nor decoded; ``stats.parse_columns_skipped`` counts them.
+    """
+    batch, skipped = columnar.read_projected(data, columns, filter_columns)
+    if skipped:
+        stats.bump(parse_columns_skipped=skipped)
+    return batch
 
 
 def _columns_itemsize(columns: Dict[str, Any]) -> int:
@@ -561,16 +576,23 @@ class DeltaTable:
         :meth:`plan_scan`, or an O(1) catalog lookup that avoided the full
         snapshot walk). Files are fetched concurrently through the shared
         executor; batches decode and yield in plan order, with ``filters``
-        applied row-wise exactly as :meth:`scan` would.
+        applied row-wise exactly as :meth:`scan` would. ``columns``
+        projects each batch; the filter columns are parsed besides when
+        the projection lacks them, and left out of the batch after.
         """
         keys = [f"{self.path}/{physical_path(add)}" for add in adds]
         names = [content_cache_key(add["contentHash"])
                  if add.get("contentHash") else None for add in adds]
+        want = set(columns) if columns is not None else None
         for data in self.io.fetch_ordered(self.store, keys,
                                           cache_names=names):
             with spans.span("store.parse"):
-                batch = columnar.read_table(data, columns)
-                batch = _apply_mask(batch, _row_mask(batch, filters))
+                batch = parse_file(data, columns, list(filters or ()),
+                                   self.io.stats)
+                mask = _row_mask(batch, filters)
+                if want is not None and len(batch) > len(want):
+                    batch = {k: v for k, v in batch.items() if k in want}
+                batch = _apply_mask(batch, mask)
             yield batch
 
     def scan(self, columns: Optional[Sequence[str]] = None, *,

@@ -129,6 +129,65 @@ def test_read_device_coo_slice():
     np.testing.assert_array_equal(np.asarray(out), want)
 
 
+def _coo_result_arrays(reader, store, ref, spec):
+    """The arrays one read of ``ref`` returns, for a bitwise compare."""
+    if reader == "read_device":
+        return [np.asarray(ref.read_device(spec))]
+    if reader == "read_slice":
+        return [ref.read_slice(spec)]
+    if reader == "read_coo":
+        t = ref.read_coo()
+        return [t.indices, t.values, np.asarray(t.shape)]
+    (out,) = store.read_many([(ref.tensor_id, spec)], device=True)
+    assert device.is_device_array(out)
+    return [np.asarray(out)]
+
+
+@pytest.mark.parametrize("reader", ["read_device", "read_slice", "read_coo",
+                                    "read_many_device"])
+def test_coo_reads_parse_only_codec_columns(reader, monkeypatch):
+    store = make_store()
+    x = sparse_tensor((24, 8, 16), density=0.1, seed=6).astype(np.float32)
+    store.put(x, tensor_id="s", layout="coo", target_file_bytes=1024)
+    spec = [(5, 17), None, (3, 11)]
+    with store.open("s") as ref:
+        filters = ref.codec.slice_filters(
+            ref.header, ((5, 17), (0, 8), (3, 11)))
+        files = len(ref._adds(filters)) if reader != "read_coo" \
+            else ref.n_chunk_files
+        # the slice crosses file boundaries without reading every file
+        assert 1 < files and (reader == "read_coo" or files < ref.n_chunk_files)
+        store.io.stats.reset()
+        got = _coo_result_arrays(reader, store, ref, spec)
+        # the per-row dense_shape block, once per file parsed
+        assert store.io_stats()["parse_columns_skipped"] == files > 0
+        monkeypatch.setattr(type(ref.codec), "chunk_columns",
+                            lambda self, header: None)
+        store.io.stats.reset()
+        want = _coo_result_arrays(reader, store, ref, spec)
+        assert store.io_stats()["parse_columns_skipped"] == 0
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    if reader != "read_coo":
+        np.testing.assert_array_equal(got[0], x[5:17, :, 3:11])
+
+
+def test_ftsf_reads_parse_every_column():
+    store = make_store()
+    x = dense((8, 4, 4), "float32", seed=13)
+    store.put(x, tensor_id="x", layout="ftsf", chunk_dims=2)
+    spec = [(1, 6), None, None]
+    with store.open("x") as ref:
+        assert ref.codec.chunk_columns(ref.header) is None
+        np.testing.assert_array_equal(np.asarray(ref.read_device(spec)), x[1:6])
+        np.testing.assert_array_equal(ref.read_slice(spec), x[1:6])
+        np.testing.assert_array_equal(ref.read(), x)
+    (out,) = store.read_many([("x", spec)], device=True)
+    np.testing.assert_array_equal(np.asarray(out), x[1:6])
+    assert store.io_stats()["parse_columns_skipped"] == 0
+
+
 @pytest.mark.parametrize("dtype", ["float64", "int64"])
 def test_read_device_noncanonical_dtype_falls_back_exact(dtype):
     # without jax x64 these would silently downcast; the path must stay numpy

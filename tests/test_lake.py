@@ -5,7 +5,7 @@ from ._hypothesis_compat import given, settings, st  # skips property tests if h
 
 from repro.lake import (CommitConflict, DeltaLog, DeltaTable, InMemoryObjectStore,
                         LatencyModel, LocalFSObjectStore, ObjectNotFoundError,
-                        PutIfAbsentError, columnar)
+                        PutIfAbsentError, ReadExecutor, columnar)
 
 
 # ---------------------------------------------------------------------------
@@ -40,6 +40,27 @@ def test_columnar_projection():
     assert set(out) == {"a"}
     with pytest.raises(KeyError):
         columnar.read_table(data, columns=["missing"])
+
+
+def test_columnar_projection_never_decodes_skipped_list_block(monkeypatch):
+    cols = {"a": np.arange(6), "dims": [np.array([4, i]) for i in range(6)],
+            "b": np.arange(6.0)}
+    data, _ = columnar.write_table(cols)
+    decoded = []
+    real = columnar._decode_column
+
+    def spy(raw, meta):
+        decoded.append(meta["name"])
+        return real(raw, meta)
+
+    monkeypatch.setattr(columnar, "_decode_column", spy)
+    out = columnar.read_table(data, columns=["b", "a"])
+    assert set(out) == {"a", "b"} and sorted(decoded) == ["a", "b"]
+    decoded.clear()
+    out, skipped = columnar.read_projected(data, ["a"], optional=["nope"])
+    assert set(out) == {"a"} and decoded == ["a"] and skipped == 2
+    decoded.clear()
+    assert columnar.read_projected(data)[1] == 0 and len(decoded) == 3
 
 
 def test_columnar_dictionary_compresses_repeats():
@@ -210,6 +231,26 @@ def test_table_append_scan_skipping():
     np.testing.assert_array_equal(sl["chunk_index"], [42, 43, 44])
     # data skipping: the slice read touched ~1 file out of 10
     assert slice_bytes < full_bytes / 5
+
+
+def test_fetch_adds_projection_without_filter_column_still_filters():
+    io = ReadExecutor(max_workers=2)
+    t = DeltaTable.create(InMemoryObjectStore(), "tensors/t1", io=io)
+    for lo in range(0, 40, 10):
+        t.append({"chunk_index": np.arange(lo, lo + 10),
+                  "val": 2 * np.arange(lo, lo + 10),
+                  "dims": [np.array([lo, i]) for i in range(10)]})
+    filters = {"chunk_index": (12, 14)}
+    adds = t.plan_scan(filters=filters)
+    assert len(adds) == 1
+    (batch,) = t.fetch_adds(adds, ["val"], filters=filters)
+    assert set(batch) == {"val"}
+    np.testing.assert_array_equal(batch["val"], [24, 26, 28])
+    assert io.stats.parse_columns_skipped == 1  # dims; chunk_index was masked
+    # no projection: every column, nothing skipped
+    (full,) = t.fetch_adds(adds, filters=filters)
+    assert set(full) == {"chunk_index", "val", "dims"}
+    assert io.stats.parse_columns_skipped == 1
 
 
 def test_table_time_travel_and_compact():
